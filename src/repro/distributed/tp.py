@@ -59,11 +59,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-try:  # moved out of experimental in newer JAX
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover
-    from jax.sharding import shard_map  # type: ignore
-
 from repro.configs.base import ArchConfig
 from repro.distributed.sharding import (ShardingPlan, _leaf_pspec, make_plan)
 from repro.models.api import Model
@@ -206,6 +201,13 @@ class ShardedServing:
         return jax.tree.map(lambda ps: NamedSharding(self.mesh, ps),
                             self.param_pspecs)
 
+    def init_params(self, key) -> Tree:
+        """``Model.init`` with every leaf created under its sharding, so
+        each device only ever holds its own shard: a model whose weights
+        exceed one device's memory can be initialised at all."""
+        return jax.jit(self.model.init,
+                       out_shardings=self.param_shardings)(key)
+
     def shard_params(self, params: Tree) -> Tree:
         return jax.tree.map(jax.device_put, params, self.param_shardings)
 
@@ -238,8 +240,8 @@ class ShardedServing:
         return jax.tree.map(lambda _: P(), tree)
 
     def _smap(self, fn, in_specs, out_specs):
-        return shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     def prefill(self, params, batch):
         """Monolithic/bucketed prefill (``Model.prefill``), sharded."""

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 
 def _kernel(x_ref, a_ref, b_ref, c_ref, y_ref, state_scr, *, chunk: int):
@@ -89,7 +88,7 @@ def ssd_scan_tpu(x, dt, a_neg, B, C, *, chunk: int = 256,
                                lambda bi, hi, ci: (bi, hi, ci, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, nc, chunk, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x_c, a_c, B_c, C_c)

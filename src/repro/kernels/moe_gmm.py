@@ -14,7 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 
 def _kernel(x_ref, w_ref, o_ref, acc_scr):
@@ -60,7 +59,7 @@ def grouped_matmul_tpu(x, w, *, block_c: int = 128, block_n: int = 128,
         out_specs=pl.BlockSpec((1, bc, bn), lambda e, i, j, k: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((E, nc * bc, nn * bn), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -51,8 +50,8 @@ def _kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
     k = k_ref[0, 0].astype(jnp.float32)  # [bs, D]
     v = v_ref[0, 0].astype(jnp.float32)
     if ks_ref is not None:  # int8 page: in-register dequant, fp32 onward
-        k = k * ks_ref[0, 0][:, None]  # [bs] scales over the head dim
-        v = v * vs_ref[0, 0][:, None]
+        k = k * ks_ref[0, 0, 0][:, None]  # [bs] scales over the head dim
+        v = v * vs_ref[0, 0, 0][:, None]
     pos = pos_ref[b]
     page = bt_ref[b, j]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -135,7 +134,7 @@ def paged_verify_tpu(q, k_pages, v_pages, block_tables, pos, *,
                           window=window, group_size=G),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, T * G, D), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables, pos, qg, kt, vt)
@@ -162,16 +161,15 @@ def paged_verify_quant_tpu(q, k_pages, v_pages, k_scales, v_scales,
         .reshape(B, Hkv, T * G, D)
     kt = k_pages.transpose(2, 0, 1, 3)  # [Hkv, P, bs, D] int8
     vt = v_pages.transpose(2, 0, 1, 3)
-    kst = k_scales.astype(jnp.float32).transpose(2, 0, 1)  # [Hkv, P, bs]
-    vst = v_scales.astype(jnp.float32).transpose(2, 0, 1)
+    # [Hkv, P, 1, bs]: a page's scale row is then a (1, bs) tile, whose
+    # last two dims equal the array's, as Mosaic requires of a block
+    kst = k_scales.astype(jnp.float32).transpose(2, 0, 1)[:, :, None]
+    vst = v_scales.astype(jnp.float32).transpose(2, 0, 1)[:, :, None]
     block_tables = block_tables.astype(jnp.int32)
     pos = pos.astype(jnp.int32)
 
     def page_map(b, h, j, bt_ref, pos_ref):
         return (h, jnp.maximum(bt_ref[b, j], 0), 0, 0)
-
-    def scale_map(b, h, j, bt_ref, pos_ref):
-        return (h, jnp.maximum(bt_ref[b, j], 0), 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # block_tables, pos
@@ -180,8 +178,8 @@ def paged_verify_quant_tpu(q, k_pages, v_pages, k_scales, v_scales,
             pl.BlockSpec((1, 1, T * G, D), lambda b, h, j, *_: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, bs, D), page_map),
             pl.BlockSpec((1, 1, bs, D), page_map),
-            pl.BlockSpec((1, 1, bs), scale_map),
-            pl.BlockSpec((1, 1, bs), scale_map),
+            pl.BlockSpec((1, 1, 1, bs), page_map),
+            pl.BlockSpec((1, 1, 1, bs), page_map),
         ],
         out_specs=pl.BlockSpec((1, 1, T * G, D),
                                lambda b, h, j, *_: (b, h, 0, 0)),
@@ -196,7 +194,7 @@ def paged_verify_quant_tpu(q, k_pages, v_pages, k_scales, v_scales,
                           window=window, group_size=G),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, T * G, D), q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables, pos, qg, kt, vt, kst, vst)

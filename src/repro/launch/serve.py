@@ -1,7 +1,10 @@
-"""Serving driver: a cloud-edge continuum of real (reduced) model engines
-behind the QLMIO router, with health tracking, hedging, and fault injection.
+"""Serving driver: a cloud-edge continuum of real model engines behind the
+QLMIO router, with health tracking, hedging, and fault injection.
 
   PYTHONPATH=src python -m repro.launch.serve --requests 24 --fail-server 1
+
+The demo cluster serves ``reduced()`` configs so that it runs anywhere;
+``chip_smoke.py`` wraps a full-width engine in the same ``EngineServer``.
 """
 from __future__ import annotations
 
@@ -12,6 +15,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.router import QLMIORouter, ServerHandle
@@ -19,16 +23,13 @@ from repro.serving.router import QLMIORouter, ServerHandle
 
 class EngineServer(ServerHandle):
     """A real ServingEngine wrapped as a continuum server.  'Latency' is the
-    engine tick count scaled by a device-speed factor (CPU container — wall
-    clock would only measure this host)."""
+    engine tick count scaled by a device-speed factor, so that the router's
+    view does not depend on the host that steps the engine."""
 
-    def __init__(self, name, arch, speed: float, model_id: int,
-                 device_id: int, is_cloud: bool, seed: int = 0, fail=False):
-        cfg = reduced(get_config(arch))
-        self.cfg = cfg
-        model = build_model(cfg)
-        params = model.init(jax.random.PRNGKey(seed))
-        self.engine = ServingEngine(model, params, max_batch=2, max_seq=96)
+    def __init__(self, name, engine: ServingEngine, speed: float,
+                 model_id: int, device_id: int, is_cloud: bool, fail=False):
+        self.engine = engine
+        self.cfg = engine.model.cfg
         self.speed = speed
         self.fail = fail
         self.uid = 0
@@ -50,14 +51,22 @@ class EngineServer(ServerHandle):
         return ticks / self.speed, True
 
 
+def _demo_engine(arch: str) -> ServingEngine:
+    model = build_model(reduced(get_config(arch)))
+    params = model.init(jax.random.PRNGKey(0))
+    return ServingEngine(model, params, max_batch=2, max_seq=96)
+
+
 def build_cluster(fail_server: int | None = None):
     servers = [
-        EngineServer("edge-0 (jetson/qwen2-0.5b)", "qwen2-0.5b", 2.0, 0, 0,
-                     False, fail=fail_server == 0),
-        EngineServer("edge-1 (3090ti/llama3.2-3b)", "llama3.2-3b", 8.0, 1, 1,
-                     False, fail=fail_server == 1),
-        EngineServer("cloud (pod/chameleon-34b)", "chameleon-34b", 32.0, 2, 2,
-                     True, fail=fail_server == 2),
+        EngineServer("edge-0 (jetson/qwen2-0.5b)", _demo_engine("qwen2-0.5b"),
+                     2.0, 0, 0, False, fail=fail_server == 0),
+        EngineServer("edge-1 (3090ti/llama3.2-3b)",
+                     _demo_engine("llama3.2-3b"), 8.0, 1, 1, False,
+                     fail=fail_server == 1),
+        EngineServer("cloud (pod/chameleon-34b)",
+                     _demo_engine("chameleon-34b"), 32.0, 2, 2, True,
+                     fail=fail_server == 2),
     ]
     return servers
 
@@ -68,6 +77,7 @@ def main():
     ap.add_argument("--fail-server", type=int, default=None)
     args = ap.parse_args()
 
+    enable_compile_cache()
     servers = build_cluster(args.fail_server)
     # simple analytic predictors for the demo (speed-based)
     speeds = np.array([s.speed for s in servers])

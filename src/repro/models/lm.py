@@ -695,7 +695,9 @@ def last_logits(cfg: ArchConfig, params, hidden_last):
 
 
 def forward_hidden(cfg: ArchConfig, params, batch, *, remat=True):
-    """Dispatch per family; returns final hidden states [B,S,d]."""
+    """Dispatch per family; returns final hidden states [B,S,d].
+    ``batch["embeds"]``/``batch["embed_mask"]`` inject embedding spans on
+    the attention family, as in ``attn_forward``."""
     if cfg.cross_attention:
         enc = whisper_encode(cfg, params, batch["encoder_frames"], remat=remat)
         return whisper_decode_forward(cfg, params, batch["tokens"], enc,
@@ -704,7 +706,9 @@ def forward_hidden(cfg: ArchConfig, params, batch, *, remat=True):
         return zamba2_forward(cfg, params, batch["tokens"], remat=remat)
     if cfg.block_kind == "xlstm":
         return xlstm_forward(cfg, params, batch["tokens"], remat=remat)
-    return attn_forward(cfg, params, batch["tokens"], remat=remat)
+    return attn_forward(cfg, params, batch["tokens"], remat=remat,
+                        embeds=batch.get("embeds"),
+                        embed_mask=batch.get("embed_mask"))
 
 
 def train_loss(cfg: ArchConfig, params, batch, *, remat=True):

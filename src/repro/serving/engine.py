@@ -134,6 +134,9 @@ class Request:
     t_submit: float = 0.0
     t_admit: float = 0.0  # when prefill work started (ends the queue span)
     token_times: list = dataclasses.field(default_factory=list)
+    # float32 [vocab] next-token logits behind each ``output`` token, kept
+    # only by an engine built with ``return_logits=True``
+    logits: list = dataclasses.field(default_factory=list, repr=False)
     # derived for multimodal requests: [T, d] float32 embedding rows and
     # the [T] bool injection mask handed to the model entry points
     features: np.ndarray | None = dataclasses.field(default=None,
@@ -225,10 +228,11 @@ class ServingEngine:
         admits ~2x the pages under int8 (the admission-control headroom
         the continuum's edge tiers trade precision for).
 
-        ``return_logits`` — the decode step normally argmaxes on device
-        and returns ``[B]`` token ids (one int32 per slot per tick over
-        the host link); True restores the full ``[B, vocab]`` logits
-        transfer for tests/inspection.
+        ``return_logits`` — the decode and verify steps normally argmax on
+        device and return token ids (one int32 per slot per tick over the
+        host link); True restores the full ``[B, vocab]`` logits transfer
+        and keeps each generated token's logits on ``Request.logits``, for
+        tests and for comparing served logits with a reference.
 
         ``draft_config`` — an ``ArchConfig`` for a small draft model
         turns on **speculative decoding** (paged backend only): each
@@ -425,12 +429,13 @@ class ServingEngine:
                 m.view(key, lambda k=key: self.pool.stats()[k])
             abstract = model.abstract_paged_cache(num_pages, page_size,
                                                   kv_dtype=kv_dtype)
-            self.cache = {name: jnp.zeros(s.shape, s.dtype)
+            # created under its sharding: each device allocates only its
+            # own shard, nothing is built whole on one device first
+            shardings = ({} if self._tp is None
+                         else self._tp.cache_shardings(abstract))
+            self.cache = {name: jnp.zeros(s.shape, s.dtype,
+                                          device=shardings.get(name))
                           for name, s in abstract.items()}
-            if self._tp is not None:
-                shardings = self._tp.cache_shardings(abstract)
-                self.cache = {name: jax.device_put(leaf, shardings[name])
-                              for name, leaf in self.cache.items()}
             self.tables = np.full((max_batch, self.max_blocks), -1, np.int32)
             self.block_tables: list[BlockTable | None] = [None] * max_batch
             self._step = self._make_step(serving.serve_step_paged)
@@ -482,13 +487,8 @@ class ServingEngine:
                 logits, cache = _base(params, cache, batch)
                 return jnp.argmax(logits, -1).astype(jnp.int32), cache
 
-            def _vstep(params, cache, batch,
-                       _base=serving.verify_step_paged):
-                logits, cache = _base(params, cache, batch)
-                return jnp.argmax(logits, -1).astype(jnp.int32), cache
-
             self._draft_step = jax.jit(_dstep, donate_argnums=(1,))
-            self._verify_step = jax.jit(_vstep, donate_argnums=(1,))
+            self._verify_step = self._make_step(serving.verify_step_paged)
         self.ticks = 0
         self._progress = False
         self.finished: list[Request] = []
@@ -498,12 +498,12 @@ class ServingEngine:
         self._auto_uid = 1_000_000_000
 
     def _make_step(self, base_step):
-        """Jit the per-tick decode step with the two per-tick-overhead
-        fixes: the cache pytree is donated (``donate_argnums``) so XLA
-        reuses its buffers instead of materializing a full KV-cache copy
-        every tick, and — unless ``return_logits`` — the greedy argmax
-        runs on device so only ``[B]`` int32 token ids cross the host
-        link instead of ``[B, vocab]`` logits."""
+        """Jit a per-tick decode (or verify) step with the two
+        per-tick-overhead fixes: the cache pytree is donated
+        (``donate_argnums``) so XLA reuses its buffers instead of
+        materializing a full KV-cache copy every tick, and — unless
+        ``return_logits`` — the greedy argmax runs on device so only int32
+        token ids cross the host link instead of logits over the vocab."""
         if self.return_logits:
             return jax.jit(base_step, donate_argnums=(1,))
 
@@ -598,9 +598,9 @@ class ServingEngine:
             self._traced.add(key)
             self._c_trace_events.inc()
 
-    def _admit_dense(self, slot: int, req: Request) -> "int | None":
+    def _admit_dense(self, slot: int, req: Request):
         """Monolithic (bucketed) prefill into a dense slot; returns the
-        first sampled token."""
+        first token's next-token logits ``[V]``."""
         req.t_admit = self._now()
         T = len(req.tokens)
         Sb = self._bucket(T)
@@ -614,7 +614,7 @@ class ServingEngine:
         self._splice(slot, rc, T)
         self._c_prefill_computed.inc(T)
         self._c_prefill_padded.inc(Sb - T)
-        return int(jnp.argmax(logits[0]))
+        return logits[0]
 
     # ----------------------------------------------------- paged internals
     def _cow_page(self, table: BlockTable, blk: int):
@@ -737,9 +737,10 @@ class ServingEngine:
             self.cache[name] = leaf.at[:, pages, offs].set(
                 leaves[:, 0, :n].astype(leaf.dtype))
 
-    def _admit_paged(self, slot: int, req: Request) -> "int | None":
-        """Monolithic (bucketed) paged prefill; returns the first sampled
-        token, or None when the pool cannot admit the request yet."""
+    def _admit_paged(self, slot: int, req: Request):
+        """Monolithic (bucketed) paged prefill; returns the first token's
+        next-token logits ``[V]``, or None when the pool cannot admit the
+        request yet."""
         reserved = self._reserve_table(req)
         if reserved is None:
             return None
@@ -788,7 +789,7 @@ class ServingEngine:
         self._c_prefix_reused.inc(n_reuse)
         self.block_tables[slot] = table
         self.tables[slot] = table.as_row(self.max_blocks)
-        return int(jnp.argmax(logits[0]))
+        return logits[0]
 
     def _free_slot(self, slot: int):
         self.slots[slot] = None
@@ -988,7 +989,7 @@ class ServingEngine:
                     :full_blocks(task.done, self.page_size)])
         if task.done >= T:  # prompt complete: promote to decoding
             self.prefill_tasks[slot] = None
-            self._activate(slot, req, int(jnp.argmax(task.logits[0])))
+            self._activate(slot, req, task.logits[0])
         return Cb
 
     def _schedule_prefill(self):
@@ -1222,11 +1223,15 @@ class ServingEngine:
             self._group_left[req.group] = left
         req.group = None
 
-    def _activate(self, slot: int, req: Request, first_tok: int):
-        """Install an admitted request into its decode slot, honoring EOS
-        and the generation budget at admission: a request whose first
-        prefill-sampled token already ends it (eos, or max_new_tokens == 1)
-        finishes immediately instead of decoding its full budget."""
+    def _activate(self, slot: int, req: Request, logits):
+        """Install an admitted request into its decode slot, given its
+        prompt's next-token ``logits`` [V], honoring EOS and the generation
+        budget at admission: a request whose first prefill-sampled token
+        already ends it (eos, or max_new_tokens == 1) finishes immediately
+        instead of decoding its full budget."""
+        first_tok = int(jnp.argmax(logits))
+        if self.return_logits:
+            req.logits.append(np.asarray(logits, np.float32))
         req.output.append(first_tok)
         req.token_times.append(self._now())
         ends = (req.max_new_tokens <= 1
@@ -1289,15 +1294,15 @@ class ServingEngine:
                 self.queue.appendleft(req)
                 break  # out of pages: wait for running requests to finish
             admit = self._admit_paged if self.paged else self._admit_dense
-            first = admit(slot, req)
-            if first is None:
+            logits = admit(slot, req)
+            if logits is None:
                 self.queue.appendleft(req)
                 break  # out of pages: wait for running requests to finish
             self._progress = True
             self._tag_group(req)
             if self._admit_quota is not None:
                 self._admit_quota -= 1
-            self._activate(slot, req, first)
+            self._activate(slot, req, logits)
 
     def step(self) -> int:
         """One engine tick: spend the prefill budget (chunked path) or
@@ -1361,7 +1366,8 @@ class ServingEngine:
         out, self.cache = self._step(self.params, self.cache, batch)
         # default path: ``out`` is already the [B] argmax token ids,
         # computed on device — one int32 per slot crosses the host link
-        nxt = np.asarray(jnp.argmax(out, -1) if self.return_logits else out)
+        out = np.asarray(out)
+        nxt = out.argmax(-1) if self.return_logits else out
         self.ticks += 1
         self._c_decode_tokens.inc(len(active))
         t_now = self._now()
@@ -1372,6 +1378,8 @@ class ServingEngine:
             req = self.slots[i]
             tok = int(nxt[i])
             req.output.append(tok)
+            if self.return_logits:
+                req.logits.append(out[i])
             req.token_times.append(t_now)
             self.pos[i] += 1
             self.budget[i] -= 1
@@ -1434,12 +1442,14 @@ class ServingEngine:
         tables = np.full_like(self.tables, -1)
         for i in active:
             tables[i] = self.tables[i]
-        ids, self.cache = self._verify_step(
+        out, self.cache = self._verify_step(
             self.params, self.cache,
             {"tokens": jnp.asarray(vt),
              "pos": jnp.asarray(np.minimum(base, self.max_seq), jnp.int32),
              "block_tables": jnp.asarray(tables)})
-        ids = np.asarray(ids)  # [B, k+1] target argmax per verify position
+        out = np.asarray(out)
+        # [B, k+1] target argmax per verify position
+        ids = out.argmax(-1) if self.return_logits else out
         t_now = self._now()
         if self._tr is not None:
             self._tr.span("draft_tick", "engine", t0, t_draft,
@@ -1460,6 +1470,8 @@ class ServingEngine:
             n_emit = len(emit)
             emitted = 0
             for tok in emit:
+                if self.return_logits:
+                    req.logits.append(out[i, emitted])
                 emitted += 1
                 req.output.append(tok)
                 ts = t0 + (t_now - t0) * emitted / n_emit

@@ -152,20 +152,46 @@ def test_abstract_paged_cache_int8_layout(qwen):
         model.abstract_paged_cache(8, 4, kv_dtype="fp4")
 
 
-def test_engine_int8_greedy_agreement(qwen):
-    """Short greedy traces must agree between the int8 and bf16 engines:
-    int8 rounding perturbs logits well below the argmax gaps of this
-    pinned workload (chunked + monolithic prefill paths both)."""
+# Largest |int8 - bf16| served logit.  Per-row symmetric int8 rounds each
+# K/V element by at most absmax/254 (test_quantize_roundtrip_bound), about
+# twice bf16's relative step; through this 2-layer model that moves logits
+# of standard deviation ~1 by at most 0.065 on this workload, so 0.15 keeps
+# a 2x margin.  A scale applied to the wrong row or page moves them by O(1).
+INT8_LOGIT_TOL = 0.15
+
+
+@pytest.mark.parametrize("prefill_chunk", [64, 0], ids=["chunked",
+                                                        "monolithic"])
+def test_engine_int8_greedy_agreement(qwen, prefill_chunk):
+    """The int8 engine's served logits agree with the bf16 engine's within
+    INT8_LOGIT_TOL at every generated position whose inputs agree.  Tokens
+    are not compared: with random weights rounding may flip a near-tied
+    argmax, after which the two engines decode different sequences."""
     cfg, model, params = qwen
     rng = _rng(3)
     prompts = [rng.integers(0, cfg.vocab, n).astype(np.int32)
                for n in (7, 19, 33, 12)]
-    _, out_bf = _serve(model, params, prompts)
-    _, out_i8 = _serve(model, params, prompts, kv_dtype="int8")
-    assert out_i8 == out_bf
-    _, out_i8_mono = _serve(model, params, prompts, kv_dtype="int8",
-                            prefill_chunk=0)
-    assert out_i8_mono == out_bf
+
+    def served(kv_dtype):
+        eng = ServingEngine(model, params, max_batch=2, max_seq=64,
+                            prefill_chunk=prefill_chunk, kv_dtype=kv_dtype,
+                            return_logits=True)
+        reqs = [Request(i, p, max_new_tokens=5)
+                for i, p in enumerate(prompts)]
+        for r in reqs:
+            eng.submit(r)
+        eng.run_until_drained()
+        return reqs
+
+    for a, b in zip(served("bf16"), served("int8")):
+        assert len(a.logits) == len(a.output) == len(b.logits) == 5
+        # logits[t] follows output[:t]: comparable up to the first
+        # differing token, inclusive
+        n = next((t for t, (x, y) in enumerate(zip(a.output, b.output))
+                  if x != y), len(a.output) - 1) + 1
+        err = max(float(np.abs(x - y).max())
+                  for x, y in zip(a.logits[:n], b.logits[:n]))
+        assert err <= INT8_LOGIT_TOL, (a.uid, err)
 
 
 def test_engine_int8_halves_cache_bytes(qwen):

@@ -248,12 +248,12 @@ def test_plan_heads_vs_seq_fallback(llama):
     kv = np.zeros((L, B, S, cfg.n_kv_heads, Dh), np.float32)
     cache = plan.cache(cfg, {"k": kv, "v": kv})
     if cfg.n_kv_heads % sz == 0:
-        assert cache["k"].spec == P(None, ("data",), None, "model", None)
+        assert cache["k"].spec == P(None, "data", None, "model", None)
     # MQA: kv-head axis can't shard -> KV-sequence fallback on S
     mqa = dataclasses.replace(cfg, n_kv_heads=1)
     kv1 = np.zeros((L, B, S, 1, Dh), np.float32)
     c1 = plan.cache(mqa, {"k": kv1})["k"].spec
-    assert c1[3] is None and c1[2] == ("model",)
+    assert c1[3] is None and c1[2] == "model"
 
 
 def test_plan_paged_pool_leaves(llama):
@@ -288,7 +288,7 @@ def test_plan_recurrent_state_leaves(llama):
     leaf = np.zeros((cfg.n_layers, 4, 2, 64), np.float32)
     spec = plan.cache(cfg, {"conv": leaf})["conv"].spec
     if 2 % mesh.shape["data"] == 0:
-        assert spec[2] == ("data",)
+        assert spec[2] == "data"
     assert spec[3] == ("model" if 64 % sz == 0 else None)
 
 
@@ -302,7 +302,7 @@ def test_plan_zero1_opt_state(llama):
     # moments reuse the param pspec plus `data` on the first free dim
     wspec = opt.m["w"].spec
     assert wspec[1] == "model"  # mlp rule
-    assert wspec[0] == ("data",)  # ZeRO-1 slot on the free embed dim
+    assert wspec[0] == "data"  # ZeRO-1 slot on the free embed dim
     assert opt.m["w"] is opt.v["w"] is not None
     # scalar step stays replicated
     assert opt.step.spec == P()
