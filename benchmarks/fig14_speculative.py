@@ -213,9 +213,9 @@ def run():
               f"{r['n_spec_dispatches']}")
         if name == "qlmio_spec" and trace_path is not None:
             tm.export(trace_path)
-            n_verify = sum(e.get("name") == "verify_tick"
+            n_verify = sum(e.get("name") == "engine.spec.verify"
                            for e in tm.tracer.events)
-            print(f"fig14,trace,{trace_path},verify_tick_spans,{n_verify}")
+            print(f"fig14,trace,{trace_path},verify_spans,{n_verify}")
 
     ac, cs, qs = (results["all_cloud"], results["cloud_spec"],
                   results["qlmio_spec"])

@@ -5,8 +5,9 @@ Reads a trace JSON exported by ``repro.serving.telemetry.Telemetry``
 top-level keys) and prints:
 
   * per-stage p50/p95 latency decomposition — every span category/name
-    pair (uplink, queue, prefill, decode, downlink, prefill_chunk,
-    tick, ...) over its recorded durations;
+    pair (uplink, queue, prefill, decode, downlink, tick, and the
+    engine's phase spans ``engine.prefill.dispatch``, ``engine.decode.*``,
+    ...) over its recorded durations;
   * per-engine utilization — busy fraction (span-covered time / trace
     horizon) per traced process, with a coarse timeline;
   * top-N slowest requests — by summed lifecycle span duration per
@@ -208,10 +209,10 @@ def report(trace: dict, top: int = 5) -> str:
     stages = stage_summary(trace)
     lines.append("== per-stage latency decomposition (seconds) ==")
     if stages:
-        lines.append(f"{'stage':<28}{'count':>7}{'p50':>10}{'p95':>10}"
+        lines.append(f"{'stage':<36}{'count':>7}{'p50':>10}{'p95':>10}"
                      f"{'total':>10}")
         for s in stages:
-            lines.append(f"{s['cat'] + '/' + s['name']:<28}"
+            lines.append(f"{s['cat'] + '/' + s['name']:<36}"
                          f"{s['count']:>7}{s['p50_s']:>10.4f}"
                          f"{s['p95_s']:>10.4f}{s['total_s']:>10.2f}")
     else:
@@ -264,7 +265,7 @@ def report(trace: dict, top: int = 5) -> str:
         # join the draft/verify engine spans into the same p50/p95 view
         # as the rest of the stage decomposition
         for s in stages:
-            if s["name"] in ("draft_tick", "verify_tick"):
+            if s["name"] in ("engine.spec.draft", "engine.spec.verify"):
                 lines.append(f"{s['cat'] + '/' + s['name']:<36}"
                              f"n={s['count']:<6} p50={s['p50_s']:.4f}s  "
                              f"p95={s['p95_s']:.4f}s  "

@@ -65,12 +65,18 @@ cache layout names in repro/models/api.py.
 Observability (repro/serving/telemetry.py): every engine owns a
 ``MetricsRegistry`` (request/token counters, TTFT/ITL/e2e histograms,
 KV-pool and XLA-trace views) — ``latency_stats()``/``stats()`` are thin
-views over it.  Passing ``telemetry=`` additionally records request
-lifecycle spans (submit→queue→prefill-chunk[i]→decode→finish) and
-per-tick batch/KV-occupancy counter samples against the engine's clock,
-exportable as Perfetto-loadable Chrome trace JSON
-(``Telemetry.export``).  With ``telemetry=None`` (default) the decode
-hot path performs no tracing work at all beyond plain counter adds.
+views over it.  Each phase of a tick is timed by one ``telemetry.span``
+call with two sinks: a profiler TraceMe, always, so that a
+``jax.profiler`` trace shows ``engine.tick`` and its phases
+(``engine.schedule``, ``engine.admit``, ``engine.prefill.dispatch``,
+``engine.kv.register``, ``engine.prefill.wait``,
+``engine.decode.{prepare,dispatch,wait,emit}``,
+``engine.spec.{draft,verify}``; ``engine.gc`` for collections) on the
+host timeline beside the device ops; and, when ``telemetry=`` has an
+enabled tracer, the same span on the engine's clock, beside request
+lifecycle spans (submit→queue→prefill→decode→finish) and per-tick
+batch/KV-occupancy counter samples, exportable as Perfetto-loadable
+Chrome trace JSON (``Telemetry.export``).
 """
 from __future__ import annotations
 
@@ -90,7 +96,8 @@ from repro.serving.kv_cache import (BlockPool, BlockTable, KVSnapshot,
                                     OutOfPagesError, ceil_blocks,
                                     full_blocks, kv_page_bytes)
 from repro.serving.request import ContinuumRequest, StreamEvent
-from repro.serving.telemetry import MetricsRegistry, latency_summary
+from repro.serving.telemetry import (MetricsRegistry, install_gc_spans,
+                                     latency_summary, span)
 
 
 def bucket_length(n: int, *, minimum: int = 16, maximum: int | None = None
@@ -283,11 +290,12 @@ class ServingEngine:
         unsharded (draft/verify traffic crosses the host anyway).
 
         ``telemetry`` — optional ``repro.serving.telemetry.Telemetry``.
-        When given (and its tracer enabled), the engine records request
-        lifecycle spans and per-tick occupancy counter samples against its
-        clock under process ``trace_name``, and registers its metrics
-        registry for export.  ``None`` keeps tracing fully off: the hot
-        path does a single ``is None`` check and no event allocation.
+        When given (and its tracer enabled), the engine records its phase
+        spans, request lifecycle spans and per-tick occupancy counter
+        samples against its clock under process ``trace_name``, and
+        registers its metrics registry for export.  With ``None`` the
+        phase spans go to the profiler's TraceMe alone and no trace event
+        is allocated.
         """
         self.model = model
         self.params = params
@@ -379,13 +387,7 @@ class ServingEngine:
         self._h_itl = m.histogram("itl_s")
         self._h_e2e = m.histogram("e2e_s")
         self._h_queue = m.histogram("queue_s")
-        # fraction of the per-tick prefill token budget actually spent
-        # (can slightly exceed 1.0: chunks are charged at bucket size);
-        # observed only on ticks that did prefill work, telemetry only
-        self._h_budget_util = m.histogram("prefill_budget_util")
-        # admission-group sizes under the saxml batching knobs, and the
-        # streamed-token counter (0 for drain-only workloads)
-        self._h_admit_size = m.histogram("batch_admit_size")
+        # streamed tokens (0 for drain-only workloads)
         self._c_stream_tokens = m.counter("stream_tokens")
         # speculative decoding: drafted = spec_k per active slot per tick;
         # accepted = drafts consumed into the output stream; wasted =
@@ -404,6 +406,7 @@ class ServingEngine:
         self._pid = self._tr.process(trace_name) if self._tr else 0
         if telemetry is not None:
             telemetry.register_metrics(trace_name, m)
+        install_gc_spans()
         if self.paged:
             self.page_size = page_size
             self.max_blocks = ceil_blocks(max_seq, page_size)
@@ -513,6 +516,11 @@ class ServingEngine:
 
         return jax.jit(step_fn, donate_argnums=(1,))
 
+    def _span(self, name: str, **args) -> span:
+        """A phase span: a profiler TraceMe, and a span of the engine's
+        tracer on its clock when tracing is on."""
+        return span(name, self._tr, self._now, pid=self._pid, **args)
+
     def page_bytes(self) -> int:
         """Bytes one page pool entry costs across all layers (K+V values
         plus int8 scale rows) — the ``kv_budget_bytes`` unit."""
@@ -610,7 +618,8 @@ class ServingEngine:
             batch["length"] = jnp.asarray([T], jnp.int32)
         mm = self._with_embeds(batch, req, 0, T, Sb)
         self._note_trace(("prefill", Sb, mm))
-        logits, rc = self._prefill(self.params, batch)
+        with self._span("engine.prefill.dispatch", uid=req.uid, tokens=T):
+            logits, rc = self._prefill(self.params, batch)
         self._splice(slot, rc, T)
         self._c_prefill_computed.inc(T)
         self._c_prefill_padded.inc(Sb - T)
@@ -741,7 +750,8 @@ class ServingEngine:
         """Monolithic (bucketed) paged prefill; returns the first token's
         next-token logits ``[V]``, or None when the pool cannot admit the
         request yet."""
-        reserved = self._reserve_table(req)
+        with self._span("engine.admit", uid=req.uid):
+            reserved = self._reserve_table(req)
         if reserved is None:
             return None
         req.t_admit = self._now()
@@ -757,7 +767,9 @@ class ServingEngine:
                 batch["length"] = jnp.asarray([T], jnp.int32)
             mm = self._with_embeds(batch, req, 0, T, Sb)
             self._note_trace(("prefill", Sb, mm))
-            logits, rc = self._prefill(self.params, batch)
+            with self._span("engine.prefill.dispatch", uid=req.uid,
+                            tokens=n_sfx):
+                logits, rc = self._prefill(self.params, batch)
             sk, sv = rc["k"], rc["v"]  # [L, 1, Sb, Hkv, Dh]
         else:
             kp, vp = self.cache["k_pages"], self.cache["v_pages"]
@@ -779,11 +791,15 @@ class ServingEngine:
                 batch["length"] = jnp.asarray([n_sfx], jnp.int32)
             mm = self._with_embeds(batch, req, n_reuse, T, Sb)
             self._note_trace(("prefill_sfx", n_reuse, Sb, mm))
-            logits, (sk, sv) = self._prefill_sfx(self.params, batch, pk, pv)
+            with self._span("engine.prefill.dispatch", uid=req.uid,
+                            tokens=n_sfx):
+                logits, (sk, sv) = self._prefill_sfx(self.params, batch,
+                                                     pk, pv)
         self._scatter_kv(table, np.arange(n_reuse, T), sk, sv, n_sfx)
         if self.prefix_caching:
-            self.pool.register_prefix(
-                toks, table.pages[:full_blocks(T, self.page_size)])
+            with self._span("engine.kv.register", uid=req.uid):
+                self.pool.register_prefix(
+                    toks, table.pages[:full_blocks(T, self.page_size)])
         self._c_prefill_computed.inc(n_sfx)
         self._c_prefill_padded.inc(Sb - n_sfx)
         self._c_prefix_reused.inc(n_reuse)
@@ -959,34 +975,31 @@ class ServingEngine:
         T = len(toks)
         n = min(self.prefill_chunk, T - task.done)
         Cb = self._bucket(n, cap=self.prefill_chunk)
-        batch = {"tokens": self._padded_prompt(toks[task.done:task.done + n],
-                                               Cb),
-                 "pos": jnp.asarray(task.done, jnp.int32),
-                 "length": jnp.asarray(n, jnp.int32)}
-        if self.paged:
-            batch["block_tables"] = jnp.asarray(self.tables[slot][None])
-        else:
-            batch["slot"] = jnp.asarray(slot, jnp.int32)
-        mm = self._with_embeds(batch, req, task.done, task.done + n, Cb)
-        self._note_trace(("prefill_chunk", Cb, mm))
-        t0 = self._now() if self._tr is not None else 0.0
-        task.logits, self.cache = self._prefill_chunk(
-            self.params, self.cache, batch)
-        if self._tr is not None:
-            self._tr.span("prefill_chunk", "prefill", t0, self._now(),
-                          pid=self._pid, tid=req.uid,
-                          args={"tokens": n, "done": task.done + n,
-                                "total": T})
+        with self._span("engine.prefill.dispatch", uid=req.uid, tokens=n,
+                        done=task.done + n, total=T):
+            batch = {"tokens": self._padded_prompt(
+                         toks[task.done:task.done + n], Cb),
+                     "pos": jnp.asarray(task.done, jnp.int32),
+                     "length": jnp.asarray(n, jnp.int32)}
+            if self.paged:
+                batch["block_tables"] = jnp.asarray(self.tables[slot][None])
+            else:
+                batch["slot"] = jnp.asarray(slot, jnp.int32)
+            mm = self._with_embeds(batch, req, task.done, task.done + n, Cb)
+            self._note_trace(("prefill_chunk", Cb, mm))
+            task.logits, self.cache = self._prefill_chunk(
+                self.params, self.cache, batch)
         task.done += n
         self._c_prefill_computed.inc(n)
         self._c_prefill_padded.inc(Cb - n)
         if self.paged and self.prefix_caching:
             # publish fully-written prompt blocks as they complete, so a
             # request admitted later this tick already hits them
-            self.pool.register_prefix(
-                toks[:task.done],
-                self.block_tables[slot].pages[
-                    :full_blocks(task.done, self.page_size)])
+            with self._span("engine.kv.register", uid=req.uid):
+                self.pool.register_prefix(
+                    toks[:task.done],
+                    self.block_tables[slot].pages[
+                        :full_blocks(task.done, self.page_size)])
         if task.done >= T:  # prompt complete: promote to decoding
             self.prefill_tasks[slot] = None
             self._activate(slot, req, task.logits[0])
@@ -1012,7 +1025,9 @@ class ServingEngine:
                              and self.prefill_tasks[i] is None), None)
                 if free is not None:
                     req = self.queue.popleft()
-                    if self._start_prefill(free, req):
+                    with self._span("engine.admit", uid=req.uid):
+                        started = self._start_prefill(free, req)
+                    if started:
                         progressed = True
                         self._tag_group(req)
                         if self._admit_quota is not None:
@@ -1029,9 +1044,6 @@ class ServingEngine:
             self._progress |= progressed
             if not progressed:
                 break
-        spent = self.prefill_budget - budget
-        if spent and self.telemetry is not None:
-            self._h_budget_util.observe(spent / self.prefill_budget)
 
     # ------------------------------------------- streaming + batched admission
     def _emit_stream(self, req: Request, tok: int, t: float, final: bool):
@@ -1085,15 +1097,11 @@ class ServingEngine:
             self._cur_group = self._next_group
             self._next_group += 1
             self._group_left[self._cur_group] = 0
-            self._cur_size = 0
         req.group = self._cur_group
         self._group_left[self._cur_group] += 1
-        self._cur_size += 1
 
     def _close_admit_group(self):
-        if self._cur_group is not None:
-            self._h_admit_size.observe(self._cur_size)
-            self._cur_group = None
+        self._cur_group = None
 
     # ------------------------------------------------------------- public
     def busy(self) -> bool:
@@ -1229,7 +1237,8 @@ class ServingEngine:
         budget at admission: a request whose first prefill-sampled token
         already ends it (eos, or max_new_tokens == 1) finishes immediately
         instead of decoding its full budget."""
-        first_tok = int(jnp.argmax(logits))
+        with self._span("engine.prefill.wait", uid=req.uid):
+            first_tok = int(jnp.argmax(logits))
         if self.return_logits:
             req.logits.append(np.asarray(logits, np.float32))
         req.output.append(first_tok)
@@ -1286,7 +1295,9 @@ class ServingEngine:
                 break  # this tick's admission group is full
             req = self.queue.popleft()
             if req.imported is not None:
-                if self._admit_imported(slot, req):
+                with self._span("engine.admit", uid=req.uid):
+                    admitted = self._admit_imported(slot, req)
+                if admitted:
                     self._tag_group(req)
                     if self._admit_quota is not None:
                         self._admit_quota -= 1
@@ -1318,13 +1329,28 @@ class ServingEngine:
         a shared virtual clock; ``run_until_drained`` is just a loop over
         this method with a *relative* ``drain_deadline`` guard, so the two
         driving styles compose (draining never depends on the global tick
-        count accumulated by earlier external stepping)."""
+        count accumulated by earlier external stepping).
+
+        A tick with work is an ``engine.tick`` span (step number, and the
+        slots decoding and prefilling and the requests queued at its
+        start) holding the spans of its phases."""
+        decoding = self.max_batch - self.slots.count(None)
+        prefilling = self.max_batch - self.prefill_tasks.count(None)
+        if not (decoding or prefilling or self.queue):
+            return self._tick()
+        with self._span("engine.tick", step_num=self.ticks,
+                        decoding=decoding, prefilling=prefilling,
+                        queued=len(self.queue)):
+            return self._tick()
+
+    def _tick(self) -> int:
         self._progress = False  # any admission/prefill advance this tick
         self._admit_quota = self._compute_admit_quota()
-        if self.chunked:
-            self._schedule_prefill()
-        else:
-            self._admit()
+        with self._span("engine.schedule"):
+            if self.chunked:
+                self._schedule_prefill()
+            else:
+                self._admit()
         self._close_admit_group()
         self._g_queue_depth.set(len(self.queue))
         active = [i for i, r in enumerate(self.slots) if r is not None]
@@ -1339,6 +1365,40 @@ class ServingEngine:
             self._spec_tick(active)
             self.ticks += 1
             return len(active) + n_prefilling
+        with self._span("engine.decode.prepare", active=len(active)):
+            batch = self._decode_batch(active)
+        with self._span("engine.decode.dispatch"):
+            out, self.cache = self._step(self.params, self.cache, batch)
+        with self._span("engine.decode.wait"):
+            # default path: ``out`` is already the [B] argmax token ids,
+            # computed on device — one int32 per slot crosses the host link
+            out = np.asarray(out)
+        t_now = self._now()
+        with self._span("engine.decode.emit"):
+            nxt = out.argmax(-1) if self.return_logits else out
+            self.ticks += 1
+            self._c_decode_tokens.inc(len(active))
+            for i in active:
+                req = self.slots[i]
+                tok = int(nxt[i])
+                req.output.append(tok)
+                if self.return_logits:
+                    req.logits.append(out[i])
+                req.token_times.append(t_now)
+                self.pos[i] += 1
+                self.budget[i] -= 1
+                ends = bool(self.budget[i] <= 0 or tok == self.eos_id
+                            or self.pos[i] >= self.max_seq - 1)
+                self._emit_stream(req, tok, t_now, ends)
+                if ends:
+                    self._finish(req)
+                    self._free_slot(i)  # free slot/pages (continuous batching)
+        return len(active) + n_prefilling
+
+    def _decode_batch(self, active: "list[int]") -> dict:
+        """The decode step's device inputs: each active slot's last token
+        and position, and on the paged path its block table, grown across
+        a page boundary where the next position needs it."""
         tokens = np.zeros(self.max_batch, np.int32)
         # slots without a decodable request (free, or still prefilling) are
         # masked out of the decode step: dense writes land at the
@@ -1362,34 +1422,7 @@ class ServingEngine:
             pos[pos >= self.max_seq] = 0  # clamp masked rows (null table)
             batch["pos"] = jnp.asarray(pos, jnp.int32)
             batch["block_tables"] = jnp.asarray(tables)
-        t0 = self._now() if self._tr is not None else 0.0
-        out, self.cache = self._step(self.params, self.cache, batch)
-        # default path: ``out`` is already the [B] argmax token ids,
-        # computed on device — one int32 per slot crosses the host link
-        out = np.asarray(out)
-        nxt = out.argmax(-1) if self.return_logits else out
-        self.ticks += 1
-        self._c_decode_tokens.inc(len(active))
-        t_now = self._now()
-        if self._tr is not None:
-            self._tr.span("decode_tick", "engine", t0, t_now, pid=self._pid,
-                          args={"active": len(active)})
-        for i in active:
-            req = self.slots[i]
-            tok = int(nxt[i])
-            req.output.append(tok)
-            if self.return_logits:
-                req.logits.append(out[i])
-            req.token_times.append(t_now)
-            self.pos[i] += 1
-            self.budget[i] -= 1
-            ends = bool(self.budget[i] <= 0 or tok == self.eos_id
-                        or self.pos[i] >= self.max_seq - 1)
-            self._emit_stream(req, tok, t_now, ends)
-            if ends:
-                self._finish(req)
-                self._free_slot(i)  # free slot/pages (continuous batching)
-        return len(active) + n_prefilling
+        return batch
 
     def _spec_tick(self, active: "list[int]"):
         """One speculative decode tick: the draft model proposes ``spec_k``
@@ -1408,56 +1441,53 @@ class ServingEngine:
         k = self.spec_k
         B = self.max_batch
         t0 = self._now()
-        # masked slots (free / mid-prefill): pos = max_seq puts every dense
-        # draft write out of bounds (dropped) and, with a null block table,
-        # every verify write/read on an invalid page (dropped/masked)
-        cur = np.zeros(B, np.int32)
-        base = np.full(B, self.max_seq, np.int64)
-        for i in active:
-            cur[i] = self.slots[i].output[-1]
-            base[i] = self.pos[i]
-        drafts = np.zeros((B, k), np.int32)
-        for t in range(k):
-            dpos = np.minimum(base + t, self.max_seq)
-            ids, self._draft_cache = self._draft_step(
-                self.draft_params, self._draft_cache,
-                {"tokens": jnp.asarray(cur),
-                 "pos": jnp.asarray(dpos, jnp.int32)})
-            cur = np.asarray(ids)
-            drafts[:, t] = cur
-        t_draft = self._now() if self._tr is not None else t0
-        # grow block tables to cover the k+1 verify positions; admission
-        # reserved spec_k slack in _total_blocks, so this cannot exhaust
-        # the pool (positions clamped at max_seq simply drop their writes)
-        for i in active:
-            bt = self.block_tables[i]
-            cap = min(int(base[i]) + k + 1, self.max_seq)
-            if cap > bt.num_tokens_capacity():
-                bt.ensure_capacity(cap)
-                self.tables[i] = bt.as_row(self.max_blocks)
-        vt = np.zeros((B, k + 1), np.int32)
-        for i in active:
-            vt[i, 0] = self.slots[i].output[-1]
-            vt[i, 1:] = drafts[i]
-        tables = np.full_like(self.tables, -1)
-        for i in active:
-            tables[i] = self.tables[i]
-        out, self.cache = self._verify_step(
-            self.params, self.cache,
-            {"tokens": jnp.asarray(vt),
-             "pos": jnp.asarray(np.minimum(base, self.max_seq), jnp.int32),
-             "block_tables": jnp.asarray(tables)})
-        out = np.asarray(out)
+        with self._span("engine.spec.draft", active=len(active), k=k):
+            # masked slots (free / mid-prefill): pos = max_seq puts every
+            # dense draft write out of bounds (dropped) and, with a null
+            # block table, every verify write/read on an invalid page
+            # (dropped/masked)
+            cur = np.zeros(B, np.int32)
+            base = np.full(B, self.max_seq, np.int64)
+            for i in active:
+                cur[i] = self.slots[i].output[-1]
+                base[i] = self.pos[i]
+            drafts = np.zeros((B, k), np.int32)
+            for t in range(k):
+                dpos = np.minimum(base + t, self.max_seq)
+                ids, self._draft_cache = self._draft_step(
+                    self.draft_params, self._draft_cache,
+                    {"tokens": jnp.asarray(cur),
+                     "pos": jnp.asarray(dpos, jnp.int32)})
+                cur = np.asarray(ids)
+                drafts[:, t] = cur
+        with self._span("engine.spec.verify", active=len(active), k=k):
+            # grow block tables to cover the k+1 verify positions;
+            # admission reserved spec_k slack in _total_blocks, so this
+            # cannot exhaust the pool (positions clamped at max_seq simply
+            # drop their writes)
+            for i in active:
+                bt = self.block_tables[i]
+                cap = min(int(base[i]) + k + 1, self.max_seq)
+                if cap > bt.num_tokens_capacity():
+                    bt.ensure_capacity(cap)
+                    self.tables[i] = bt.as_row(self.max_blocks)
+            vt = np.zeros((B, k + 1), np.int32)
+            for i in active:
+                vt[i, 0] = self.slots[i].output[-1]
+                vt[i, 1:] = drafts[i]
+            tables = np.full_like(self.tables, -1)
+            for i in active:
+                tables[i] = self.tables[i]
+            out, self.cache = self._verify_step(
+                self.params, self.cache,
+                {"tokens": jnp.asarray(vt),
+                 "pos": jnp.asarray(np.minimum(base, self.max_seq),
+                                    jnp.int32),
+                 "block_tables": jnp.asarray(tables)})
+            out = np.asarray(out)
         # [B, k+1] target argmax per verify position
         ids = out.argmax(-1) if self.return_logits else out
         t_now = self._now()
-        if self._tr is not None:
-            self._tr.span("draft_tick", "engine", t0, t_draft,
-                          pid=self._pid, args={"active": len(active),
-                                               "k": k})
-            self._tr.span("verify_tick", "engine", t_draft, t_now,
-                          pid=self._pid, args={"active": len(active),
-                                               "k": k})
         n_tok = tick_acc = 0
         for i in active:
             req = self.slots[i]

@@ -1,4 +1,4 @@
-"""Continuum telemetry: request tracing, metrics registry, dispatch audit.
+"""Continuum telemetry: engine spans, metrics registry, dispatch audit.
 
 The paper's central difficulty is that generation quality and inference
 latency are *highly difficult to predict* for MLLM offloading — but the
@@ -7,9 +7,18 @@ see where a request's virtual seconds went, why the router picked a
 server, or how wrong the dispatch-time latency prediction was.  This
 module is the shared observability substrate for the serving stack:
 
+  * ``span``            — the one span helper the engine times its phases
+    with.  Each call has two sinks.  It always writes a profiler TraceMe
+    (``jax.profiler.TraceAnnotation``), so under ``jax.profiler`` the
+    engine's phases land on the host timeline of the same ``.xplane.pb``
+    as the device ops, on one clock; with no profiler session that costs
+    about a microsecond.  When the caller passes an enabled ``Tracer`` the
+    same call also records the span there, on the caller's clock.
+    ``install_gc_spans`` adds ``engine.gc`` spans for the interpreter's
+    generation 1 and 2 collections.
   * ``Tracer``          — per-request lifecycle spans
-    (uplink→queue→prefill→decode→downlink, plus per-chunk prefill spans,
-    engine ticks and media-encode transfers), recorded against whatever
+    (uplink→queue→prefill→decode→downlink, plus the engine's phase spans
+    and media-encode transfers), recorded against whatever
     clock the engine runs on — wall time for a standalone
     ``ServingEngine``, the shared **virtual clock** for the continuum
     replay harness — so live and replayed runs produce comparable traces.
@@ -28,19 +37,20 @@ module is the shared observability substrate for the serving stack:
     finishes, making the paper's "latency is hard to predict" claim a
     measured, regression-gated number (``prediction_error``).
 
-Zero-cost-when-off contract: components accept ``telemetry=None`` and
-guard every tracing site behind a single attribute check; with tracing
-disabled no span/event objects are allocated on the decode hot path.
-``Telemetry(trace=False)`` keeps the metrics registry and the dispatch
-audit live (both are O(1) per *request*, not per tick) while recording no
-trace events at all.
+Cost when off: components accept ``telemetry=None`` and then pass no
+``Tracer`` to ``span``, so no span or event objects of this module are
+kept; only the TraceMe is made.  ``Telemetry(trace=False)`` keeps the
+metrics registry and the dispatch audit live (both are O(1) per
+*request*, not per tick) while recording no trace events at all.
 """
 from __future__ import annotations
 
 import dataclasses
+import gc
 import json
 
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 # ---------------------------------------------------------------- metrics
 
@@ -238,6 +248,73 @@ class Tracer:
             meta.append({"name": "thread_name", "ph": "M", "pid": pid,
                          "tid": 0, "args": {"name": "engine"}})
         return meta + self.events
+
+
+# ------------------------------------------------------------------ spans
+
+
+class span:
+    """One timed phase, written to both sinks by one call site:
+
+        with span("engine.decode.wait", tracer, clock, pid=pid):
+            ...
+
+    The profiler sink is a TraceMe named ``name`` carrying ``args`` (a
+    ``StepTraceAnnotation`` when ``step_num`` is among them); it is always
+    made and costs about a microsecond with no profiler session.  The
+    ``Tracer`` sink, when ``tracer`` is given, records the span on
+    ``clock``'s seconds under process ``pid``; a span with a ``uid`` arg
+    lies on that request's thread row."""
+
+    __slots__ = ("_me", "_tr", "_clock", "_t0", "_name", "_pid", "_args")
+
+    def __init__(self, name: str, tracer: "Tracer | None" = None,
+                 clock=None, *, pid: int = 0, **args):
+        annotation = (StepTraceAnnotation if "step_num" in args
+                      else TraceAnnotation)
+        self._me = annotation(name, **args)
+        self._tr, self._clock = tracer, clock
+        self._name, self._pid, self._args = name, pid, args
+
+    def __enter__(self):
+        self._me.__enter__()
+        if self._tr is not None:
+            self._t0 = self._clock()
+        return self
+
+    def __exit__(self, *exc):
+        if self._tr is not None:
+            self._tr.span(self._name, "engine", self._t0, self._clock(),
+                          pid=self._pid, tid=self._args.get("uid", 0),
+                          args=self._args or None)
+        self._me.__exit__(*exc)
+
+
+class _GcSpans:
+    """``gc.callbacks`` hook: an ``engine.gc`` span around each collection
+    of generation 1 or 2 (generation 0 runs too often and too briefly to
+    stall a tick)."""
+
+    def __init__(self):
+        self._open = None
+
+    def __call__(self, phase: str, info: dict):
+        gen = info["generation"]
+        if gen == 0:
+            return
+        if phase == "start":
+            self._open = TraceAnnotation("engine.gc", generation=gen)
+            self._open.__enter__()
+        elif self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+
+def install_gc_spans():
+    """Put ``engine.gc`` spans on the profiler's host timeline, once per
+    process however often it is called."""
+    if not any(isinstance(cb, _GcSpans) for cb in gc.callbacks):
+        gc.callbacks.append(_GcSpans())
 
 
 # ---------------------------------------------------------- dispatch audit

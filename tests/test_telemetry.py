@@ -1,11 +1,14 @@
-"""Continuum telemetry layer (ISSUE-6 tentpole).
+"""Continuum telemetry layer.
 
 Covers the metrics registry primitives, Chrome-trace schema + lifecycle
 span ordering under the virtual clock, zero-cost-when-disabled on the
 decode hot path, dispatch-audit join correctness, the steady-state
 recompile guard (warmed engines re-traced nothing across a mixed
-replay), per-tier latency rollups, and the trace_report CLI.
+replay), per-tier latency rollups, the trace_report CLI, and the
+engine's phase spans in a ``jax.profiler`` trace and in the ``Tracer``.
 """
+import gc
+import glob
 import json
 import os
 import sys
@@ -268,3 +271,134 @@ def test_steady_state_no_recompiles(traced_world):
         assert h.engine.metrics.snapshot()["xla_trace_events"] == 0, \
             f"{h.name} re-traced in steady state"
         assert h.engine.jit_cache_sizes() == before
+
+
+# ------------------------------------------------------ engine phase spans
+
+
+def _engine(telemetry=None, **kw):
+    cfg = reduced(get_config("qwen2-0.5b"))
+    model = build_model(cfg)
+    return ServingEngine(model, model.init(jax.random.PRNGKey(0)),
+                         max_batch=2, max_seq=64, prefill_chunk=8,
+                         telemetry=telemetry, **kw)
+
+
+def _requests(uid0: int, n: int = 3) -> "list[Request]":
+    """Prompts of 12, 17 and 22 tokens: two or three 8-token chunks each,
+    more requests than slots so that some wait for admission."""
+    rng = np.random.default_rng(uid0)
+    return [Request(uid0 + i, rng.integers(1, 100, 12 + 5 * i).astype(
+        np.int32), max_new_tokens=3) for i in range(n)]
+
+
+def _host_spans(trace_dir: str) -> "list[tuple]":
+    """(name, start_ns, end_ns, stats) of every ``engine.*`` event on the
+    host planes of the profile in ``trace_dir``, by start."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("engine."):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return sorted(out, key=lambda h: h[1])
+
+
+@pytest.fixture(scope="module")
+def profiled(tmp_path_factory):
+    """The host spans of a warmed engine serving three requests, then a
+    forced collection, all under the profiler."""
+    eng = _engine()
+    for r in _requests(100):
+        eng.submit(r)
+    eng.run_until_drained()  # compile every shape before the trace
+    trace_dir = str(tmp_path_factory.mktemp("profile"))
+    jax.profiler.start_trace(trace_dir)
+    try:
+        for r in _requests(200):
+            eng.submit(r)
+        eng.run_until_drained()
+        gc.collect()
+    finally:
+        jax.profiler.stop_trace()
+    return _host_spans(trace_dir)
+
+
+def test_decode_phases_lie_inside_their_tick_in_order(profiled):
+    phases = ["engine.decode.prepare", "engine.decode.dispatch",
+              "engine.decode.wait", "engine.decode.emit"]
+    ticks = [h for h in profiled if h[0] == "engine.tick"]
+    assert ticks and all("step_num" in h[3] for h in ticks)
+    decode = [h for h in profiled if h[0] in phases]
+    assert len(decode) == 4 * sum(
+        1 for h in profiled if h[0] == "engine.decode.wait")
+    for name, a, b, _ in decode:
+        assert any(ta <= a and b <= tb for _, ta, tb, _ in ticks), name
+    for _, ta, tb, _ in ticks:
+        inside = [h[0] for h in decode if ta <= h[1] and h[2] <= tb]
+        assert inside in ([], phases)
+
+
+def test_admission_comes_before_the_first_chunk(profiled):
+    admits = {h[3]["uid"]: h for h in profiled if h[0] == "engine.admit"}
+    chunks: dict = {}
+    for h in profiled:
+        if h[0] == "engine.prefill.dispatch":
+            chunks.setdefault(h[3]["uid"], h)
+    assert sorted(admits) == sorted(chunks) == [200, 201, 202]
+    for uid, (_, _, end, _) in admits.items():
+        assert end <= chunks[uid][1]
+    # every chunk's blocks are registered, every prompt's first token read
+    assert sum(h[0] == "engine.kv.register" for h in profiled) == sum(
+        h[0] == "engine.prefill.dispatch" for h in profiled)
+    assert sorted(h[3]["uid"] for h in profiled
+                  if h[0] == "engine.prefill.wait") == [200, 201, 202]
+
+
+def test_forced_collection_is_a_gc_span(profiled):
+    assert any(h[0] == "engine.gc" and h[3]["generation"] == 2
+               for h in profiled)
+
+
+@pytest.mark.parametrize("speculative", [False, True],
+                         ids=["plain", "speculative"])
+def test_tracer_receives_the_phase_spans(speculative):
+    """With a tracing ``Telemetry`` each phase span also reaches the
+    ``Tracer``, per-request ones on the request's row, and
+    ``trace_report`` reads them."""
+    from scripts.trace_report import report, stage_summary
+
+    tm = Telemetry(trace=True)
+    extra = {}
+    if speculative:
+        extra = dict(draft_config=reduced(get_config("qwen2-0.5b")),
+                     spec_k=2)
+    eng = _engine(tm, **extra)
+    for r in _requests(300):
+        eng.submit(r)
+    eng.run_until_drained()
+    trace = tm.to_json()
+    spans = [e for e in trace["traceEvents"] if e["ph"] == "X"]
+    names = {e["name"] for e in spans}
+    assert {"engine.tick", "engine.schedule", "engine.admit",
+            "engine.prefill.dispatch", "engine.kv.register",
+            "engine.prefill.wait"} <= names
+    steps = ({"engine.spec.draft", "engine.spec.verify"} if speculative
+             else {"engine.decode.prepare", "engine.decode.dispatch",
+                   "engine.decode.wait", "engine.decode.emit"})
+    assert steps <= names
+    chunks = [e for e in spans if e["name"] == "engine.prefill.dispatch"]
+    assert {e["tid"] for e in chunks} == {300, 301, 302}
+    assert all(e["args"]["uid"] == e["tid"] for e in chunks)
+    stages = {s["name"] for s in stage_summary(trace)}
+    assert names <= stages
+    text = report(trace)
+    assert "engine/engine.prefill.dispatch" in text
+    if speculative:
+        assert "engine/engine.spec.verify" in text.split(
+            "speculative decoding")[1]
