@@ -127,33 +127,128 @@ def test_cow_on_shared_or_registered_page():
 # ------------------------------------------------------------ kernel parity
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("B,H,Hkv,D,bs,window", [
-    (2, 8, 2, 64, 16, 0),    # GQA
-    (2, 4, 4, 32, 8, 24),    # MHA + sliding window
-    (1, 8, 1, 64, 32, 0),    # MQA
-])
-def test_paged_decode_kernel_parity(B, H, Hkv, D, bs, window, dtype):
-    tol = dict(atol=5e-2, rtol=5e-2) if dtype == jnp.bfloat16 else \
-        dict(atol=2e-4, rtol=2e-4)
-    NB, P = 5, 1 + 2 * B * 5
-    q = jnp.asarray(RNG.normal(size=(B, H, D)), dtype)
-    kp = jnp.asarray(RNG.normal(size=(P, bs, Hkv, D)), dtype)
-    vp = jnp.asarray(RNG.normal(size=(P, bs, Hkv, D)), dtype)
-    lens = RNG.integers(bs, NB * bs, B)
+def _paged_case(rng, B, H, Hkv, D, bs, NB, lens, masked, dtype):
+    """q, pools, block table and positions: slot b holds ``lens[b]``
+    positions on distinct random pages (page 0, the null page, never
+    used); a masked slot has a null table and ``pos`` 0, as the engine
+    gives a free or mid-prefill slot."""
+    P = 1 + B * NB
+    q = jnp.asarray(rng.normal(size=(B, H, D)), dtype)
+    kp = jnp.asarray(rng.normal(size=(P, bs, Hkv, D)), dtype)
+    vp = jnp.asarray(rng.normal(size=(P, bs, Hkv, D)), dtype)
+    lens = rng.integers(bs, NB * bs, B) if lens is None else np.asarray(lens)
     bt = np.full((B, NB), -1, np.int32)
-    perm = RNG.permutation(np.arange(1, P))
+    perm = rng.permutation(np.arange(1, P))
     used = 0
     for b, n in enumerate(lens):
+        if b in masked:
+            continue
         nb = -(-int(n) // bs)
         bt[b, :nb] = perm[used:used + nb]
         used += nb
-    pos = jnp.asarray(lens - 1, jnp.int32)
+    pos = np.where(np.isin(np.arange(B), masked), 0, lens - 1)
+    return q, kp, vp, bt, jnp.asarray(pos, jnp.int32)
+
+
+def _dead_pages(bt, pos, bs, window):
+    """Every page that holds no position a slot can see: never allocated,
+    the null page, or wholly before a slot's window."""
+    live = set()
+    for row, p in zip(bt, np.asarray(pos)):
+        first = max(int(p) - window + 1, 0) // bs if window else 0
+        live.update(int(x) for x in row[first:int(p) // bs + 1] if x >= 0)
+    return np.asarray(sorted(set(range(bt.size + 1)) - live))
+
+
+# (B, H, Hkv, D, bs, window, NB, lens, masked); lens None draws them
+PAGED_CASES = [
+    pytest.param(2, 8, 2, 64, 16, 0, 5, None, (), id="2-8-2-64-16-0"),  # GQA
+    pytest.param(2, 4, 4, 32, 8, 24, 5, None, (),
+                 id="2-4-4-32-8-24"),  # MHA + sliding window
+    pytest.param(1, 8, 1, 64, 32, 0, 5, None, (), id="1-8-1-64-32-0"),  # MQA
+    # ragged lengths in one batch: one position, ends on a page boundary
+    # (16, 32, 128 positions), and a masked row (null table, pos 0)
+    pytest.param(6, 8, 2, 64, 16, 0, 12, (1, 16, 32, 17, 128, 0), (5,),
+                 id="ragged-masked"),
+    # a long table (NB 32) with 1-3 live pages
+    pytest.param(3, 8, 2, 64, 16, 0, 32, (5, 20, 48), (),
+                 id="long-table-short"),
+    # window 40 at position 150: the first visible block is block 6
+    pytest.param(2, 8, 2, 64, 16, 40, 12, (151, 100), (),
+                 id="window-past-block0"),
+    # 16-page compute blocks (D 64) over 20 and 13 live pages
+    pytest.param(2, 8, 2, 64, 16, 0, 24, (320, 200), (), id="blocks-ragged"),
+    # D 128: one position a copied row (D 64 packs two)
+    pytest.param(2, 4, 2, 128, 16, 0, 6, (40, 90), (), id="d128"),
+]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("B,H,Hkv,D,bs,window,NB,lens,masked", PAGED_CASES)
+def test_paged_decode_kernel_parity(B, H, Hkv, D, bs, window, NB, lens,
+                                    masked, dtype):
+    tol = dict(atol=5e-2, rtol=5e-2) if dtype == jnp.bfloat16 else \
+        dict(atol=2e-4, rtol=2e-4)
+    q, kp, vp, bt, pos = _paged_case(RNG, B, H, Hkv, D, bs, NB, lens,
+                                     masked, dtype)
     bt = jnp.asarray(bt)
-    out = ops.paged_decode(q, kp, vp, bt, pos, window=window)
+    out = np.asarray(ops.paged_decode(q, kp, vp, bt, pos, window=window),
+                     np.float32)
+    want = np.asarray(ref.paged_decode_ref(q, kp, vp, bt, pos, window=window),
+                      np.float32)
+    seen = np.setdiff1d(np.arange(B), masked)
+    np.testing.assert_allclose(out[seen], want[seen], **tol)
+    assert (out[list(masked)] == 0).all()  # no live page: zeros
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("window", [0, 40])
+def test_paged_decode_kernel_skips_dead_pages(window, dtype):
+    """Pages no slot can see, the null page among them, are never read:
+    filled with NaN they leave the output equal to the reference on the
+    clean pool, and finite.  A walk over every block slot multiplies their
+    V by p = 0, and 0 * NaN is NaN."""
+    B, H, Hkv, D, bs, NB = 5, 8, 2, 64, 16, 12
+    q, kp, vp, bt, pos = _paged_case(np.random.default_rng(11), B, H, Hkv,
+                                     D, bs, NB, (1, 16, 151, 60, 0), (4,),
+                                     dtype)
+    dead = _dead_pages(bt, pos, bs, window)
+    assert 0 in dead
+    bt = jnp.asarray(bt)
     want = ref.paged_decode_ref(q, kp, vp, bt, pos, window=window)
+    out = ops.paged_decode(q, kp.at[dead].set(jnp.nan),
+                           vp.at[dead].set(jnp.nan), bt, pos, window=window)
+    out = np.asarray(out, np.float32)
+    assert np.isfinite(out).all()
+    tol = 5e-2 if dtype == jnp.bfloat16 else 2e-4
+    np.testing.assert_allclose(out[:4], np.asarray(want, np.float32)[:4],
+                               atol=tol, rtol=tol)
+    assert (out[4] == 0).all()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_decode_kernel_masks_table_holes(dtype):
+    """An unallocated entry inside a slot's live range is neither copied
+    nor seen, as the reference masks it; its V rows cannot leak."""
+    q, kp, vp, bt, pos = _paged_case(RNG, 2, 8, 2, 64, 16, 6, (60, 90), (),
+                                     dtype)
+    bt[0, 1] = bt[1, 0] = -1
+    bt = jnp.asarray(bt)
+    out = ops.paged_decode(q, kp, vp.at[0].set(jnp.nan), bt, pos)
+    want = ref.paged_decode_ref(q, kp, vp, bt, pos)
+    tol = 5e-2 if dtype == jnp.bfloat16 else 2e-4
     np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(want, np.float32), **tol)
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+
+
+def test_paged_decode_kernel_rejects_head_dim_off_lanes():
+    """A page is copied in whole 128-lane rows: a head dim that neither
+    divides 128 nor is a multiple of it is refused, not mis-read."""
+    q, kp, vp, bt, pos = _paged_case(RNG, 1, 2, 1, 80, 16, 2, (20,), (),
+                                     jnp.float32)
+    with pytest.raises(ValueError, match="head dim 80"):
+        ops.paged_decode(q, kp, vp, jnp.asarray(bt), pos)
 
 
 def test_paged_matches_dense_flash_decode():

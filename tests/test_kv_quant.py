@@ -62,32 +62,91 @@ def test_quantize_zero_rows_safe():
 # -------------------------------------------------- fused-dequant kernels
 
 
-@pytest.mark.parametrize("B,S,H,Hkv,D,bs,window", [
-    (2, 96, 8, 2, 64, 16, 0),
-    (1, 64, 4, 4, 32, 8, 24),
-])
-def test_paged_decode_quant_kernel_parity(B, S, H, Hkv, D, bs, window):
-    rng = _rng(7)
+def _quant_case(rng, B, S, H, Hkv, D, bs, lens, masked):
+    """q, bf16 and int8 pools, block table and positions.  ``lens`` None:
+    every slot's table full, positions drawn from [S/2, S).  Otherwise
+    slot b holds ``lens[b]`` positions on its first pages, and a masked
+    slot has a null table and ``pos`` 0."""
     NB = S // bs
     P = 1 + B * NB
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.bfloat16)
     k = jnp.asarray(rng.normal(size=(P, bs, Hkv, D)), jnp.bfloat16)
     v = jnp.asarray(rng.normal(size=(P, bs, Hkv, D)), jnp.bfloat16)
+    bt = np.arange(1, 1 + B * NB, dtype=np.int32).reshape(B, NB)
+    if lens is None:
+        pos = rng.integers(S // 2, S, B)
+    else:
+        pos = np.asarray(lens) - 1
+        for b, n in enumerate(lens):
+            bt[b, -(-n // bs):] = -1
+        bt[list(masked)] = -1
+        pos[list(masked)] = 0
+    return q, k, v, bt, jnp.asarray(pos, jnp.int32)
+
+
+# (B, S, H, Hkv, D, bs, window, lens, masked); lens None: full tables
+QUANT_CASES = [
+    pytest.param(2, 96, 8, 2, 64, 16, 0, None, (), id="2-96-8-2-64-16-0"),
+    pytest.param(1, 64, 4, 4, 32, 8, 24, None, (), id="1-64-4-4-32-8-24"),
+    # ragged: one position, page boundaries, a masked row; NB 32 with 1-3
+    # live pages; 16-page compute blocks over 20 live pages
+    pytest.param(6, 512, 8, 2, 64, 16, 0, (1, 16, 32, 40, 320, 0), (5,),
+                 id="ragged-long-table-masked"),
+    # window 40 at position 150: the first visible block is block 6
+    pytest.param(2, 192, 8, 2, 64, 16, 40, (151, 100), (),
+                 id="window-past-block0"),
+]
+
+
+@pytest.mark.parametrize("B,S,H,Hkv,D,bs,window,lens,masked", QUANT_CASES)
+def test_paged_decode_quant_kernel_parity(B, S, H, Hkv, D, bs, window, lens,
+                                          masked):
+    q, k, v, bt, pos = _quant_case(_rng(7), B, S, H, Hkv, D, bs, lens,
+                                   masked)
+    bt = jnp.asarray(bt)
     k8, ks = quantize_kv(k)
     v8, vs = quantize_kv(v)
-    bt = jnp.asarray(np.arange(1, 1 + B * NB).reshape(B, NB), jnp.int32)
-    pos = jnp.asarray(rng.integers(S // 2, S, B), jnp.int32)
-    out = ops.paged_decode_quant(q, k8, v8, ks, vs, bt, pos, window=window)
+    out = np.asarray(ops.paged_decode_quant(q, k8, v8, ks, vs, bt, pos,
+                                            window=window), np.float32)
     want = ref.paged_decode_quant_ref(q, k8, v8, ks, vs, bt, pos,
                                       window=window)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(want, np.float32),
+    seen = np.setdiff1d(np.arange(B), masked)
+    np.testing.assert_allclose(out[seen], np.asarray(want, np.float32)[seen],
                                atol=5e-3, rtol=5e-3)
+    assert (out[list(masked)] == 0).all()  # no live page: zeros
     # and the dequant noise vs the full-precision pool stays int8-sized
     full = ref.paged_decode_ref(q, k, v, bt, pos, window=window)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(full, np.float32),
+    np.testing.assert_allclose(out[seen], np.asarray(full, np.float32)[seen],
                                atol=5e-2, rtol=5e-2)
+
+
+@pytest.mark.parametrize("window", [0, 40])
+def test_paged_decode_quant_skips_dead_pages(window):
+    """Pages no slot can see, the null page among them, are never read:
+    with NaN scales they leave the output equal to the reference on the
+    clean pool, and finite."""
+    B, S, bs = 5, 192, 16
+    q, k, v, bt, pos = _quant_case(_rng(5), B, S, 8, 2, 64, bs,
+                                   (1, 16, 151, 60, 0), (4,))
+    live = set()
+    for row, p in zip(bt, np.asarray(pos)):
+        first = max(int(p) - window + 1, 0) // bs if window else 0
+        live.update(int(x) for x in row[first:int(p) // bs + 1] if x >= 0)
+    dead = np.asarray(sorted(set(range(k.shape[0])) - live))
+    assert 0 in dead
+    bt = jnp.asarray(bt)
+    k8, ks = quantize_kv(k)
+    v8, vs = quantize_kv(v)
+    want = ref.paged_decode_quant_ref(q, k8, v8, ks, vs, bt, pos,
+                                      window=window)
+    out = ops.paged_decode_quant(q, k8, v8, ks.at[dead].set(jnp.nan),
+                                 vs.at[dead].set(jnp.nan), bt, pos,
+                                 window=window)
+    out = np.asarray(out, np.float32)
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out[:4], np.asarray(want, np.float32)[:4],
+                               atol=5e-3, rtol=5e-3)
+    assert (out[4] == 0).all()
 
 
 def test_paged_decode_quant_masks_unallocated():

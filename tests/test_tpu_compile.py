@@ -9,7 +9,9 @@ program contains the Mosaic kernel.
 
 Two shape sets: qwen2-0.5b at its published widths (14 query / 2 KV heads of
 64) and one TP=4 shard of codeqwen1.5-7b (8 query / 8 KV heads of 128), both
-with 16-token pages, a 2048-token block table and the 8-slot decode batch.
+with 16-token pages, a 2048-token block table and the 8-slot decode batch;
+the decode kernels, whose walk follows each slot's live pages, also at an
+8192-token table.
 
 The topology is described inside a fixture and never at import: only one
 process may load the TPU library at a time, and the test workers all import
@@ -28,8 +30,6 @@ from repro.kernels.paged_decode import paged_decode_quant_tpu, paged_decode_tpu
 from repro.kernels.paged_verify import paged_verify_quant_tpu, paged_verify_tpu
 
 B, PAGE, MAX_SEQ, SPEC_K = 8, 16, 2048, 3
-NB = MAX_SEQ // PAGE
-NUM_PAGES = 1 + B * NB
 
 # (H, Hkv, D) per shape set
 SHAPES = {"qwen2-0.5b": (14, 2, 64), "codeqwen1.5-7b-tp4-shard": (8, 8, 128)}
@@ -64,19 +64,21 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-def _args(sharding, shape_set, *, verify, quant):
+def _args(sharding, shape_set, *, verify, quant, max_seq=MAX_SEQ):
     H, Hkv, D = SHAPES[shape_set]
+    nb = max_seq // PAGE
+    num_pages = 1 + B * nb
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
     q = sds((B, SPEC_K + 1, H, D) if verify else (B, H, D), jnp.bfloat16)
-    pages = sds((NUM_PAGES, PAGE, Hkv, D), jnp.int8 if quant else jnp.bfloat16)
+    pages = sds((num_pages, PAGE, Hkv, D), jnp.int8 if quant else jnp.bfloat16)
     args = [q, pages, pages]
     if quant:
-        scales = sds((NUM_PAGES, PAGE, Hkv), jnp.float32)
+        scales = sds((num_pages, PAGE, Hkv), jnp.float32)
         args += [scales, scales]
-    args += [sds((B, NB), jnp.int32), sds((B,), jnp.int32)]
+    args += [sds((B, nb), jnp.int32), sds((B,), jnp.int32)]
     return args
 
 
@@ -94,5 +96,17 @@ def test_kernel_compiles_for_v5e(kernel, shape_set, one_chip,
                                  no_compile_cache):
     fn, verify, quant = KERNELS[kernel]
     args = _args(one_chip, shape_set, verify=verify, quant=quant)
+    compiled = fn.lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("shape_set", list(SHAPES))
+@pytest.mark.parametrize("kernel", ["paged_decode", "paged_decode_quant"])
+def test_decode_kernel_compiles_for_v5e_long_table(kernel, shape_set,
+                                                   one_chip,
+                                                   no_compile_cache):
+    fn, verify, quant = KERNELS[kernel]
+    args = _args(one_chip, shape_set, verify=verify, quant=quant,
+                 max_seq=8192)
     compiled = fn.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
