@@ -55,6 +55,7 @@ import functools
 from typing import Any
 
 import jax
+import jax.extend.core as jex
 import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
@@ -65,6 +66,39 @@ from repro.models.api import Model
 from repro.nn.spec import tree_map_specs
 
 Tree = Any
+
+
+def gathered_bytes(jaxpr: jex.Jaxpr, n_devices: int) -> int:
+    """Bytes the ``all_gather``s of a traced program move, summed over its
+    ``n_devices`` chips.  A gather whose result is F bytes on each of the n
+    chips of its group hands each chip the n - 1 shards it lacks, so it
+    moves (n - 1) x F per group.  A gather in a ``scan`` counts once per
+    iteration, in a ``cond`` the costliest branch counts, and in a
+    ``while`` body once (its trip count is not known from shapes)."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "all_gather":
+            n = int(eqn.params["axis_size"])
+            total += (n - 1) * (n_devices // n) * sum(
+                v.aval.size * v.aval.dtype.itemsize for v in eqn.outvars)
+            continue
+        subs = [gathered_bytes(j, n_devices) for j in _sub_jaxprs(eqn.params)]
+        if name == "cond":
+            total += max(subs, default=0)
+        else:
+            total += sum(subs) * (eqn.params["length"] if name == "scan"
+                                  else 1)
+    return total
+
+
+def _sub_jaxprs(params: dict):
+    for v in params.values():
+        for j in (v if isinstance(v, (tuple, list)) else (v,)):
+            if isinstance(j, jex.ClosedJaxpr):
+                yield j.jaxpr
+            elif isinstance(j, jex.Jaxpr):
+                yield j
 
 
 def serving_mesh(tp: int, devices=None) -> Mesh:
@@ -242,6 +276,14 @@ class ShardedServing:
     def _smap(self, fn, in_specs, out_specs):
         return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
                              out_specs=out_specs, check_vma=False)
+
+    def gather_bytes(self, jitted, *args) -> int:
+        """Bytes the all-gathers of ``jitted``'s program move at these
+        argument shapes, summed over chips (``gathered_bytes``).  Traced
+        through ``jitted.trace``, whose trace the following call reuses, so
+        the count adds no second trace of the program."""
+        return gathered_bytes(jitted.trace(*args).jaxpr.jaxpr,
+                              self.mesh.size)
 
     def prefill(self, params, batch):
         """Monolithic/bucketed prefill (``Model.prefill``), sharded."""

@@ -332,6 +332,7 @@ class ServingEngine:
             self.params = self._tp.shard_params(params)
         else:
             self._tp = None
+        self.tp = 1 if self._tp is None else self._tp.tp  # mesh width
         serving = self._tp if self._tp is not None else model
         self.return_logits = return_logits
         self.bucketing = bucket_prompts and model.supports_bucketed_prefill
@@ -383,6 +384,15 @@ class ServingEngine:
         # new XLA traces since the last metrics.reset() — the steady-state
         # recompile guard asserts this stays 0 on a warmed engine
         self._c_trace_events = m.counter("xla_trace_events")
+        # tensor parallel: the bytes the sharded programs' all-gathers move,
+        # summed over chips, counted once a program shape from its trace
+        # (``_count_gathers``) and added per decode (or verify) step and per
+        # prefill; ``tp_gather_bytes`` is their sum
+        self._c_tp_gather_decode = m.counter("tp_gather_bytes_decode")
+        self._c_tp_gather_prefill = m.counter("tp_gather_bytes_prefill")
+        m.view("tp_gather_bytes", lambda: self._c_tp_gather_decode.value
+               + self._c_tp_gather_prefill.value)
+        self._tp_gather: dict = {}  # program shape key -> bytes a call
         self._h_ttft = m.histogram("ttft_s")
         self._h_itl = m.histogram("itl_s")
         self._h_e2e = m.histogram("e2e_s")
@@ -606,6 +616,18 @@ class ServingEngine:
             self._traced.add(key)
             self._c_trace_events.inc()
 
+    def _count_gathers(self, counter, key: tuple, jitted, *args):
+        """Add to ``counter`` the bytes the all-gathers of ``jitted``'s
+        sharded program move in one call (summed over chips), counted from
+        its trace on the first call at each program shape ``key``; nothing
+        without a mesh."""
+        if self._tp is None:
+            return
+        n = self._tp_gather.get(key)
+        if n is None:
+            n = self._tp_gather[key] = self._tp.gather_bytes(jitted, *args)
+        counter.inc(n)
+
     def _admit_dense(self, slot: int, req: Request):
         """Monolithic (bucketed) prefill into a dense slot; returns the
         first token's next-token logits ``[V]``."""
@@ -769,6 +791,9 @@ class ServingEngine:
             self._note_trace(("prefill", Sb, mm))
             with self._span("engine.prefill.dispatch", uid=req.uid,
                             tokens=n_sfx):
+                self._count_gathers(self._c_tp_gather_prefill,
+                                    ("prefill", Sb, mm), self._prefill,
+                                    self.params, batch)
                 logits, rc = self._prefill(self.params, batch)
             sk, sv = rc["k"], rc["v"]  # [L, 1, Sb, Hkv, Dh]
         else:
@@ -793,6 +818,10 @@ class ServingEngine:
             self._note_trace(("prefill_sfx", n_reuse, Sb, mm))
             with self._span("engine.prefill.dispatch", uid=req.uid,
                             tokens=n_sfx):
+                self._count_gathers(self._c_tp_gather_prefill,
+                                    ("prefill_sfx", n_reuse, Sb, mm),
+                                    self._prefill_sfx, self.params, batch,
+                                    pk, pv)
                 logits, (sk, sv) = self._prefill_sfx(self.params, batch,
                                                      pk, pv)
         self._scatter_kv(table, np.arange(n_reuse, T), sk, sv, n_sfx)
@@ -987,6 +1016,10 @@ class ServingEngine:
                 batch["slot"] = jnp.asarray(slot, jnp.int32)
             mm = self._with_embeds(batch, req, task.done, task.done + n, Cb)
             self._note_trace(("prefill_chunk", Cb, mm))
+            self._count_gathers(self._c_tp_gather_prefill,
+                                ("prefill_chunk", Cb, mm),
+                                self._prefill_chunk, self.params, self.cache,
+                                batch)
             task.logits, self.cache = self._prefill_chunk(
                 self.params, self.cache, batch)
         task.done += n
@@ -1331,14 +1364,14 @@ class ServingEngine:
         driving styles compose (draining never depends on the global tick
         count accumulated by earlier external stepping).
 
-        A tick with work is an ``engine.tick`` span (step number, and the
-        slots decoding and prefilling and the requests queued at its
-        start) holding the spans of its phases."""
+        A tick with work is an ``engine.tick`` span (step number, the
+        mesh width ``tp``, and the slots decoding and prefilling and the
+        requests queued at its start) holding the spans of its phases."""
         decoding = self.max_batch - self.slots.count(None)
         prefilling = self.max_batch - self.prefill_tasks.count(None)
         if not (decoding or prefilling or self.queue):
             return self._tick()
-        with self._span("engine.tick", step_num=self.ticks,
+        with self._span("engine.tick", step_num=self.ticks, tp=self.tp,
                         decoding=decoding, prefilling=prefilling,
                         queued=len(self.queue)):
             return self._tick()
@@ -1368,6 +1401,8 @@ class ServingEngine:
         with self._span("engine.decode.prepare", active=len(active)):
             batch = self._decode_batch(active)
         with self._span("engine.decode.dispatch"):
+            self._count_gathers(self._c_tp_gather_decode, ("decode",),
+                                self._step, self.params, self.cache, batch)
             out, self.cache = self._step(self.params, self.cache, batch)
         with self._span("engine.decode.wait"):
             # default path: ``out`` is already the [B] argmax token ids,
@@ -1478,12 +1513,15 @@ class ServingEngine:
             tables = np.full_like(self.tables, -1)
             for i in active:
                 tables[i] = self.tables[i]
-            out, self.cache = self._verify_step(
-                self.params, self.cache,
-                {"tokens": jnp.asarray(vt),
-                 "pos": jnp.asarray(np.minimum(base, self.max_seq),
-                                    jnp.int32),
-                 "block_tables": jnp.asarray(tables)})
+            vbatch = {"tokens": jnp.asarray(vt),
+                      "pos": jnp.asarray(np.minimum(base, self.max_seq),
+                                         jnp.int32),
+                      "block_tables": jnp.asarray(tables)}
+            self._count_gathers(self._c_tp_gather_decode, ("verify",),
+                                self._verify_step, self.params, self.cache,
+                                vbatch)
+            out, self.cache = self._verify_step(self.params, self.cache,
+                                                vbatch)
             out = np.asarray(out)
         # [B, k+1] target argmax per verify position
         ids = out.argmax(-1) if self.return_logits else out

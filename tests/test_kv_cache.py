@@ -180,6 +180,10 @@ PAGED_CASES = [
     pytest.param(2, 8, 2, 64, 16, 0, 24, (320, 200), (), id="blocks-ragged"),
     # D 128: one position a copied row (D 64 packs two)
     pytest.param(2, 4, 2, 128, 16, 0, 6, (40, 90), (), id="d128"),
+    # one TP=4 shard of codeqwen1.5-7b: 8 query heads over one KV head of
+    # 128, ragged over page boundaries, a masked row
+    pytest.param(3, 8, 1, 128, 16, 0, 12, (33, 160, 0), (2,),
+                 id="tp4-shard-8-1-128"),
 ]
 
 

@@ -95,6 +95,10 @@ QUANT_CASES = [
     # window 40 at position 150: the first visible block is block 6
     pytest.param(2, 192, 8, 2, 64, 16, 40, (151, 100), (),
                  id="window-past-block0"),
+    # one TP=4 shard of codeqwen1.5-7b: 8 query heads over one KV head of
+    # 128 (one position a copied row), ragged, a masked row
+    pytest.param(3, 192, 8, 1, 128, 16, 0, (33, 160, 0), (2,),
+                 id="tp4-shard-8-1-128"),
 ]
 
 

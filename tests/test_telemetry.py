@@ -334,6 +334,7 @@ def test_decode_phases_lie_inside_their_tick_in_order(profiled):
               "engine.decode.wait", "engine.decode.emit"]
     ticks = [h for h in profiled if h[0] == "engine.tick"]
     assert ticks and all("step_num" in h[3] for h in ticks)
+    assert all(h[3].get("tp") == 1 for h in ticks)  # the mesh width
     decode = [h for h in profiled if h[0] in phases]
     assert len(decode) == 4 * sum(
         1 for h in profiled if h[0] == "engine.decode.wait")

@@ -408,3 +408,38 @@ def test_continuum_live_tp_engine(llama):
     h = handles[0]
     assert h.engine.mesh is not None and h.engine._tp.tp == 2
     assert h.decode_tick_s > 0
+
+
+# ------------------------------------------------------ gathered bytes
+
+
+@needs_mesh
+def test_gathered_bytes_counts_scan_iterations_and_the_costlier_branch():
+    """``gathered_bytes``: a gather whose result is F bytes a chip moves
+    (n - 1) x F over its group of n; a ``scan`` body counts once per
+    iteration, a ``cond`` its costlier branch."""
+    import jax.numpy as jnp
+    from repro.distributed.tp import gathered_bytes
+    mesh = Mesh(np.asarray(jax.devices()[:4]), ("model",))
+
+    def body(x):  # x: [2, 8] float32 a chip
+        def step(c, _):  # [2, 32] float32 gathered: 256 B a chip
+            return c, jax.lax.all_gather(c, "model", axis=1, tiled=True)
+        _, ys = jax.lax.scan(step, x, None, length=3)
+        z = jax.lax.cond(  # [4, 2, 8] float32: 256 B a chip, or nothing
+            x[0, 0] > 0, lambda v: jax.lax.all_gather(v, "model"),
+            lambda v: jnp.zeros((4,) + v.shape, v.dtype), x)
+        return ys, z
+
+    f = jax.shard_map(body, mesh=mesh, in_specs=P(None, "model"),
+                      out_specs=(P(), P()), check_vma=False)
+    jaxpr = jax.jit(f).trace(jnp.ones((2, 32), jnp.float32)).jaxpr.jaxpr
+    assert gathered_bytes(jaxpr, 4) == 3 * (3 * 256) + 3 * 256
+
+
+def test_tp_gather_bytes_stay_zero_on_one_device(llama):
+    _, model, params = llama
+    eng, _ = _serve(model, params)
+    snap = eng.metrics.snapshot()
+    assert eng.tp == 1
+    assert snap["tp_gather_bytes"] == snap["tp_gather_bytes_decode"] == 0
