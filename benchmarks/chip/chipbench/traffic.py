@@ -21,6 +21,9 @@ A mix (``benchmarks/chip/traffic/<name>.json``) gives parameters only:
 ``first_output``    closed loop: ``"residual"`` draws each client's first
                     request from the length-biased residual of
                     ``output_tokens``, so the batch starts in steady state.
+``reorder``         closed loop: the seed reorders each run of this many
+                    consecutive requests (a divisor of the number of
+                    clients; default 1, no reordering).
 
 A distribution is ``{"dist": "lognormal", "median", "sigma", "min",
 "max"}`` or ``{"dist": "uniform", "min", "max"}`` (whole numbers, both
@@ -29,8 +32,16 @@ ends included).
 Every size and gap is a stratified draw: the same quantiles of the
 distribution for every seed, put in an order drawn from the seed.  An open
 loop sends a fixed set of asks: round(rate * seconds) of them, each kind's
-share of them exact.  So two seeds send the same work in a different order,
-and the spread between runs measures the system, not the draw.
+share of them exact.  A closed loop's n-th request, whichever client
+sends it, takes the n-th entry of one sequence of sizes: blocks of one
+request a slot, each a stratified set of (prompt, output) pairs, in an
+order that is the same for every seed; the seed reorders only each run of
+``reorder`` consecutive requests.  A window sees just a few blocks (the
+slots' first requests, those queued behind them, the next round), so a
+seed that reordered whole blocks would change which sizes run together,
+and so the work inside the window.  So two seeds send the same work in a
+different order, and the spread between runs measures the system, not the
+draw.
 """
 from __future__ import annotations
 
@@ -95,6 +106,15 @@ def stratified(dist: dict, n: int, rng) -> np.ndarray:
     return rng.permutation(quantiles(dist, _u(n)))
 
 
+def blocks(values: np.ndarray, width: int, rng) -> np.ndarray:
+    """``values`` (a multiple of ``width`` of them) as rows of ``width``,
+    column i of every row one value of the i-th of ``width`` strata of
+    consecutive ranks, so every row is a stratified set; ``rng`` deals
+    each stratum's values to the rows."""
+    strata = np.sort(values).reshape(width, -1)
+    return np.stack([rng.permutation(row) for row in strata], axis=1)
+
+
 def _shares(media: list, n: int, rng) -> list:
     """Exactly round(share * n) tasks of each media kind (None: text only),
     in an order drawn from the seed."""
@@ -126,6 +146,7 @@ class Traffic:
             self.rate = float(arr["rate_per_s"])
             self.items = self._open(arr, seconds)
         elif self.loop == "closed":
+            self.max_batch = max_batch
             self.clients = int(mix["clients_per_slot"]) * max_batch
             self._closed_setup()
         else:
@@ -224,22 +245,36 @@ class Traffic:
         rng = self.rng
         self._streams = [np.random.default_rng([self.rows_seed, c])
                          for c in range(C)]
-        # sizes: a stratified pool per request index, dealt to clients
-        self._depth = 16  # requests a client can send before the pool wraps
-        n = C * self._depth
-        self._prompt = stratified(mix["text_tokens"], n, rng)
-        self._out = stratified(mix["output_tokens"], n, rng)
+        # sizes: the n-th request sent takes entry n of one sequence
+        # (wrapping after C * 16): blocks of one request a slot, prompt
+        # stratum i with output stratum pair[i], in a fixed order; the
+        # first C entries are the requests sent at set-up
+        n, B = C * 16, self.max_batch
+        g = int(mix.get("reorder", 1))
+        if C % g:
+            raise ValueError(f"reorder {g} does not divide {C} clients")
+        fixed = np.random.default_rng(0)  # the same sequence for every seed
+        pair = fixed.permutation(B)
+        prompt = blocks(quantiles(mix["text_tokens"], _u(n)), B, fixed)
+        out = blocks(quantiles(mix["output_tokens"], _u(n)), B, fixed)[:, pair]
         if mix.get("first_output") == "residual":
-            self._out[:C] = rng.permutation(
-                residual_quantiles(mix["output_tokens"], _u(C)))
+            out[:C // B] = blocks(residual_quantiles(
+                mix["output_tokens"], _u(C)), B, fixed)[:, pair]
+        base = np.stack([fixed.permutation(B) for _ in range(n // B)])
+        order = np.concatenate([k + rng.permutation(g)
+                                for k in range(0, n, g)])
+        self._prompt = np.take_along_axis(prompt, base, 1).ravel()[order]
+        self._out = np.take_along_axis(out, base, 1).ravel()[order]
         self._sent = [0] * C
+        self._n = 0
 
     def next_for(self, client: int) -> Item:
-        """Client ``client``'s next request (the same for every run of a
-        seed, whatever order the clients finish in)."""
+        """The next request, sent by ``client``: its sizes the next entry
+        of the run's sequence, its tokens from the client's own stream."""
         k = self._sent[client]
         self._sent[client] += 1
-        j = (k % self._depth) * self.clients + client
+        j = self._n % len(self._prompt)
+        self._n += 1
         rng = self._streams[client]
         return self._item(text=self._tokens(int(self._prompt[j]), rng),
                           max_new=int(self._out[j]), client=client, ask=k)

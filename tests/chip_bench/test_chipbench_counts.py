@@ -22,7 +22,7 @@ def n_params(m):
 def test_parameter_counts_match_the_published_models():
     assert n_params(QWEN) == 494_032_768  # Qwen2-0.5B: 0.49B
     assert n_params(CODEQWEN) == 7_250_284_544  # CodeQwen1.5-7B: 7.25B
-    # the program's own config file states 32 KV heads: 0.94B more
+    # 32 KV heads (multi-head attention) would add 0.94B
     # (K and V weights and biases of 28 more heads in 32 layers)
     assert n_params(dict(CODEQWEN, num_key_value_heads=32)) - \
         n_params(CODEQWEN) == 32 * 2 * (4096 + 1) * (32 - 4) * 128

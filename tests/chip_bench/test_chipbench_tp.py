@@ -1,10 +1,11 @@
 """The cloud tier's shard on the CPU: codeqwen1.5-7b's head ratios (32
 query heads over 4 KV heads) at TP=4 over four host devices, so that each
-shard holds 8 query heads over one KV head.  Chunked prefill, then decode
-through the paged pool, against the plain float32 reference's full forward
-pass in logits; the ``tp_gather_bytes`` counters against a hand count; the
-``tp`` argument of the ``engine.tick`` span; and the ``tp.gather_gbps``
-reader on a synthetic trace."""
+shard holds 8 query heads over one KV head.  The pool's shards, each one
+whole KV head by its values, whatever the order of the storage axes;
+chunked prefill, then decode through the paged pool, against the plain
+float32 reference's full forward pass in logits; the ``tp_gather_bytes``
+counters against a hand count; the ``tp`` argument of the ``engine.tick``
+span; and the ``tp.gather_gbps`` reader on a synthetic trace."""
 from __future__ import annotations
 
 from types import SimpleNamespace
@@ -89,14 +90,74 @@ def _reference_logits(m: dict, ids, fp8: bool = False) -> np.ndarray:
                                  head, precision=jax.lax.Precision.HIGHEST))
 
 
+def _nonzero_sorted(a) -> np.ndarray:
+    a = np.asarray(a, np.float32).ravel()
+    return np.sort(a[a != 0])
+
+
+def assert_one_kv_head_a_shard(logical: np.ndarray, stored: jax.Array):
+    """Each of ``stored``'s shards holds exactly one whole KV head of the
+    logical view ``logical`` ``[L, P, PAGE, Hkv, D]``, whatever the order
+    of the storage axes: one shard a head, each with an equal share of the
+    pool's bytes, room for every logical element (a layout may pad), and a
+    one-to-one map from shards to heads by their sorted nonzero values
+    (unwritten pages and any padding read zero)."""
+    shards = stored.addressable_shards
+    hkv = logical.shape[3]
+    assert len(shards) == hkv
+    assert all(s.data.nbytes * hkv == stored.nbytes for s in shards)
+    assert stored.size >= logical.size
+    heads = [_nonzero_sorted(logical[..., h, :]) for h in range(hkv)]
+    assert all(h.size for h in heads)
+    held = []
+    for s in shards:
+        vals = _nonzero_sorted(s.data)
+        held.append([h for h in range(hkv)
+                     if np.array_equal(vals, heads[h])])
+    assert sorted(map(tuple, held)) == [(h,) for h in range(hkv)], held
+
+
 def test_each_shard_holds_eight_query_heads_over_one_kv_head(served):
     local = served.eng._tp.local_model.cfg
     assert (local.n_heads, local.n_kv_heads, local.hd) == (8, 1, 16)
-    pages = served.eng.cache["k_pages"]
-    L, P = MODEL["num_hidden_layers"], pages.shape[1]
-    assert pages.shape == (L, P, PAGE, 4, 16)
-    assert {s.data.shape for s in pages.addressable_shards} == {
-        (L, P, PAGE, 1, 16)}
+    L, P = MODEL["num_hidden_layers"], served.eng.pool.num_pages
+    export = served.eng.model.export_paged_kv(served.eng.cache, range(P))
+    for name in ("k_pages", "v_pages"):
+        assert export[name].shape == (L, P, PAGE, 4, 16)
+        assert_one_kv_head_a_shard(export[name], served.eng.cache[name])
+
+
+_SYN = (2, 8, PAGE, 4, 16)  # L, P, PAGE, Hkv, D
+
+
+def _head_major(x: np.ndarray) -> np.ndarray:
+    """``[L, P, 16, Hkv, 16]`` as ``[L, Hkv, P, 2, 128]``: the kernel's rows
+    of 8 positions x 16 dims, each head's pages together."""
+    L, P, bs, hkv, d = x.shape
+    return x.transpose(0, 3, 1, 2, 4).reshape(L, hkv, P, 2, bs * d // 2)
+
+
+@pytest.mark.parametrize("storage, axis, one_head_a_shard", [
+    ("page_major", 3, True),     # today's pool, split on its head axis
+    ("head_major", 1, True),     # [L, 4, P, 2, 128], split on axis 1
+    ("page_major", 1, False),    # split along pages
+    ("page_major", 2, False),    # split along in-page positions
+    ("page_major", 4, False),    # split along the head dim
+], ids=["heads", "head_major", "pages", "positions", "head_dim"])
+def test_shard_check_on_synthetic_pools(storage, axis, one_head_a_shard):
+    rng = np.random.default_rng(SEED)
+    n = int(np.prod(_SYN))
+    logical = ((rng.permutation(n) + 1) / n).astype(np.float32).reshape(_SYN)
+    host = _head_major(logical) if storage == "head_major" else logical
+    spec = [None] * host.ndim
+    spec[axis] = "model"
+    stored = jax.device_put(host, jax.sharding.NamedSharding(
+        serving_mesh(TP), jax.sharding.PartitionSpec(*spec)))
+    if one_head_a_shard:
+        assert_one_kv_head_a_shard(logical, stored)
+    else:
+        with pytest.raises(AssertionError):
+            assert_one_kv_head_a_shard(logical, stored)
 
 
 def test_prefill_then_paged_decode_match_the_reference(served):

@@ -104,27 +104,73 @@ def test_modality_lengths_and_repeats():
     assert longest <= 2047  # fits max_seq 2048
 
 
+def closed(seed, mix=CLOSED):
+    return tf.Traffic(mix, seed=seed, seconds=30.0, vocab=V, d_model=D,
+                      max_batch=64)
+
+
 def test_closed_loop_streams_and_residual_first_requests():
-    def stream(seed, clients):
-        tr = tf.Traffic(CLOSED, seed=seed, seconds=30.0, vocab=V, d_model=D,
-                        max_batch=64)
+    def sent(seed, clients):
+        tr = closed(seed)
         assert tr.clients == 128
-        return tr, {c: [tr.next_for(c) for _ in range(3)]
-                    for c in clients}
-    # clients finish in any order: a client's k-th request is fixed
-    tr, a = stream(11, range(128))
-    _, b = stream(11, reversed(range(128)))
-    for c in range(tr.clients):
-        for x, y in zip(a[c], b[c]):
-            assert np.array_equal(x.text, y.text) and x.max_new == y.max_new
-    firsts = np.array([a[c][0].max_new for c in a])
-    later = np.array([a[c][k].max_new for c in a for k in (1, 2)])
+        return tr, [tr.next_for(c) for _ in range(3) for c in clients]
+    # clients finish in any order: the n-th request sent has fixed sizes,
+    # whichever client sends it; tokens come from the sender's own stream,
+    # the same for every run of a seed
+    tr, a = sent(11, range(128))
+    _, b = sent(11, list(reversed(range(128))))
+    _, c = sent(11, range(128))
+    assert [(len(x.text), x.max_new) for x in a] == \
+        [(len(y.text), y.max_new) for y in b]
+    assert [x.client for x in b[:3]] == [127, 126, 125]
+    assert all(np.array_equal(x.text, y.text) for x, y in zip(a, c))
+    firsts = np.array([x.max_new for x in a[:tr.clients]])
+    later = np.array([x.max_new for x in a[tr.clients:]])
     assert later.min() >= 256 and later.max() <= 640
     # the residual of U[256, 640] is spread over 1..640, mean ~ 233
     assert firsts.min() < 100 and 180 < firsts.mean() < 290
-    lens = np.array([len(a[c][k].text) for c in a for k in range(3)])
+    lens = np.array([len(x.text) for x in a])
     assert lens.min() >= 64 and lens.max() <= 1280
     assert 470 <= np.median(lens) <= 560
+
+
+def sizes(tr, n):
+    return np.array([(len(x.text), x.max_new)
+                     for x in (tr.next_for(0) for _ in range(n))])
+
+
+def test_closed_loop_sends_the_same_sizes_for_every_seed():
+    """Every seed sends one sequence of (prompt, output) pairs, reordered
+    only within each run of ``reorder`` requests; each block of one
+    request a slot is a stratified set."""
+    g, B, C = CLOSED["reorder"], 64, 128
+    n = C * 16
+    a, b = sizes(closed(1), n), sizes(closed(2), n)
+    assert not np.array_equal(a, b)
+    for k in range(0, n, g):
+        assert sorted(map(tuple, a[k:k + g])) == \
+            sorted(map(tuple, b[k:k + g]))
+    # block j's i-th smallest prompt lies in the i-th of B strata of the
+    # whole pool; so do its outputs, the first C against the residuals'
+    prompts = np.sort(tf.quantiles(CLOSED["text_tokens"], tf._u(n)))
+    outs = np.sort(tf.quantiles(CLOSED["output_tokens"], tf._u(n)))
+    res = np.sort(tf.residual_quantiles(CLOSED["output_tokens"], tf._u(C)))
+    for j in range(n // B):
+        blk = a[j * B:(j + 1) * B]
+        for col, pool in ((0, prompts), (1, res if j < C // B else outs)):
+            strata = pool.reshape(B, -1)
+            v = np.sort(blk[:, col])
+            assert np.all(strata[:, 0] <= v) and np.all(v <= strata[:, -1])
+    # the sequence wraps after C * 16 requests
+    assert np.array_equal(sizes(closed(1), n + 5)[n:], a[:5])
+
+
+def test_closed_loop_reorder_must_divide_the_clients():
+    with pytest.raises(ValueError, match="reorder"):
+        closed(1, dict(CLOSED, reorder=7))
+    one = dict(CLOSED, reorder=1)
+    assert np.array_equal(sizes(closed(1, one), 256),
+                          sizes(closed(2, one), 256))
 
 
 @pytest.mark.parametrize("dist,lo,hi", [
